@@ -21,6 +21,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.process import enable_compile_cache
+
 
 def _timed(fn, *args, repeat=1, **kw):
     t0 = time.perf_counter()
@@ -153,6 +155,7 @@ BENCHES = [fig3_formats, tab1_3_area, tab4_5_power_energy, quant_matmul,
 
 
 def main() -> None:
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     for b in BENCHES:
         if only and only not in b.__name__:

@@ -38,6 +38,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.process import enable_compile_cache
+
 # A/B arms: KV-cache storage format of the lane (weights fixed at the
 # paper's posit16 deployment corner so the pairing isolates the cache).
 KV_ARMS = {"bf16": None, "posit16": "posit16", "posit12": "posit12",
@@ -238,6 +240,7 @@ def run_width_sweep(built, requests, max_new_tokens, max_prompt, seed):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=None,
                     help="prompts to serve (default 8; 4 with --smoke)")

@@ -76,6 +76,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.process import enable_compile_cache, force_host_devices
+
 
 def build_forest(seed: int = 123):
     from repro.apps.cough import train_reference_forest
@@ -905,14 +907,11 @@ def main():
         if args.trace or args.obs_ab:
             ap.error("--trace/--obs-ab run in-process; worker-pool "
                      "telemetry is the per-worker metrics snapshot rollup")
-    if args.devices > 1:
-        # the forced host device split must land in the environment before
-        # the FIRST jax import in this process (forest training below
-        # already imports jax) — append, never clobber, inherited flags
-        flag = f"--xla_force_host_platform_device_count={args.devices}"
-        prev = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in prev:
-            os.environ["XLA_FLAGS"] = (prev + " " + flag).strip()
+    # the forced host device split must land in the environment before
+    # the first JAX backend starts in this process (forest training below
+    # starts one)
+    force_host_devices(args.devices)
+    enable_compile_cache()
 
     forest = None
     if args.ab or args.smoke_baseline or args.quire_ab or args.obs_ab:

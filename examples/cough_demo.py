@@ -8,6 +8,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.process import enable_compile_cache
+
+enable_compile_cache()
+
 from repro.apps.cough import run_cough_detection
 
 FMTS = ["fp32", "posit24", "posit16", "posit16e3", "bfloat16", "fp16"]

@@ -5,6 +5,10 @@ Run: PYTHONPATH=src python examples/quickstart.py
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.process import enable_compile_cache
+
+enable_compile_cache()
+
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -7,6 +7,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.process import enable_compile_cache
+
+enable_compile_cache()
+
 from repro.apps.bayeslope import run_rpeak_detection
 
 FMTS = ["fp32", "posit16", "posit12", "posit10", "posit8",

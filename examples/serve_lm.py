@@ -14,12 +14,14 @@ import numpy as np
 
 from repro.configs import CONFIGS, reduced
 from repro.launch.mesh import make_debug_mesh_info
+from repro.launch.process import enable_compile_cache
 from repro.models import build_model
 from repro.serve import (AGGRESSIVE_SERVE, PAPER_SERVE, ServeConfig,
                          ServingEngine)
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced(CONFIGS["gemma2-2b"])
     minfo = make_debug_mesh_info()
     with minfo.mesh:

@@ -34,6 +34,7 @@ from repro.apps.metrics import rpeak_f1
 from repro.data.biosignals import (ECG_FS, cough_stream_signals,
                                    ecg_stream_signal, ragged_chunks)
 from repro.ingest import Supervisor
+from repro.launch.process import enable_compile_cache
 from repro.stream import (EscalationPolicy, PrecisionRouter, StreamEngine,
                           cough_pipeline, rpeak_pipeline)
 
@@ -67,6 +68,7 @@ def stream_frail_only(forest, sig, escalate):
 
 
 def main():
+    enable_compile_cache()
     print("training the offline forest (float32 reference features)...")
     forest = train_reference_forest(64, 7, n_trees=8, depth=5)
 

@@ -15,10 +15,12 @@ import dataclasses
 
 from repro.configs import CONFIGS
 from repro.core.policy import QuantPolicy
+from repro.launch.process import enable_compile_cache
 from repro.launch.train import train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     args = ap.parse_args()

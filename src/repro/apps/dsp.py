@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arith import Arith, get_fused_kernels, get_round_backend
+from repro.core.posit import round_to_posit
 
 
 # ---------------------------------------------------------------------------
@@ -63,18 +64,29 @@ class FFTPlan:
         self.n = n
         self.levels = n.bit_length() - 1
         ar = Arith.make(fmt_name)
-        dt = jnp.dtype(dtype_name)
         self.stages: List[Tuple[np.ndarray, np.ndarray]] = []
-        # plans are built lazily on first use, which may be inside a jit
-        # trace — escape it so the tables materialize as real constants
-        with jax.ensure_compile_time_eval():
-            for s in range(1, self.levels + 1):
-                m = 1 << s
-                half = m // 2
-                ang = -2.0 * np.pi * np.arange(half) / m
-                wr = np.asarray(ar.rnd(jnp.asarray(np.cos(ang), dt)))
-                wi = np.asarray(ar.rnd(jnp.asarray(np.sin(ang), dt)))
-                self.stages.append((wr, wi))
+        for s in range(1, self.levels + 1):
+            m = 1 << s
+            ang = -2.0 * np.pi * np.arange(m // 2) / m
+            self.stages.append((_rounded_table(ar, np.cos(ang), dtype_name),
+                                _rounded_table(ar, np.sin(ang), dtype_name)))
+
+
+def _rounded_table(ar: Arith, table: np.ndarray,
+                   dtype_name: str) -> np.ndarray:
+    """A constant table rounded onto ``ar``'s lattice, as numpy.
+
+    Tables are built lazily on first use, which may be inside a jit trace:
+    escape it so they materialize as real constants.  Posit tables round
+    through the plain jnp path, bit-identical to every round backend: a
+    Pallas kernel cannot be traced under compile-time evaluation (its
+    constants would be captured as arrays, which Pallas refuses).
+    """
+    with jax.ensure_compile_time_eval():
+        x = jnp.asarray(table, jnp.dtype(dtype_name))
+        if ar.is_posit:
+            return np.asarray(round_to_posit(x, ar.fmt))
+        return np.asarray(ar.rnd(x))
 
 
 @functools.lru_cache(maxsize=None)
@@ -447,9 +459,7 @@ def spectral_features(ar: Arith, psd: jax.Array, sr: float) -> jax.Array:
 def _dct_basis(n: int, k: int, fmt_name: str, dtype_name: str) -> np.ndarray:
     basis = np.cos(np.pi / n * (np.arange(n) + 0.5)[None, :]
                    * np.arange(k)[:, None])
-    ar = Arith.make(fmt_name)
-    with jax.ensure_compile_time_eval():
-        return np.asarray(ar.rnd(jnp.asarray(basis, jnp.dtype(dtype_name))))
+    return _rounded_table(Arith.make(fmt_name), basis, dtype_name)
 
 
 @functools.lru_cache(maxsize=None)
@@ -467,9 +477,7 @@ def _mel_filterbank(n: int, sr: float, n_mel: int, fmt_name: str,
             fb[i, a:b] = np.linspace(0, 1, b - a, endpoint=False)
         if c > b:
             fb[i, b:c] = np.linspace(1, 0, c - b, endpoint=False)
-    ar = Arith.make(fmt_name)
-    with jax.ensure_compile_time_eval():
-        return np.asarray(ar.rnd(jnp.asarray(fb, jnp.dtype(dtype_name))))
+    return _rounded_table(Arith.make(fmt_name), fb, dtype_name)
 
 
 def _dct2(ar: Arith, x: jax.Array, k: int) -> jax.Array:

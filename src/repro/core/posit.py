@@ -218,55 +218,58 @@ def round_posit_math(x: jax.Array, fmt: PositFormat) -> jax.Array:
     Bit-identity vs the codec oracle is tested exhaustively (tests/).
     """
     n, es = fmt.n, fmt.es
+    # Signed containers throughout: the sign bit is split off first, so
+    # every magnitude below fits a non-negative int32 (int64 for f64), and
+    # Mosaic lowers no unsigned compare/clamp on the TPU.
     if x.dtype == jnp.float64:
-        U, mbits, ebits, bias = jnp.uint64, 52, 11, 1023
+        I, mbits, ebits, bias = jnp.int64, 52, 11, 1023
         nan_bits, dtype = 0x7FF8000000000000, jnp.float64
     else:
         x = x.astype(jnp.float32)
-        U, mbits, ebits, bias = jnp.uint32, 23, 8, 127
+        I, mbits, ebits, bias = jnp.int32, 23, 8, 127
         nan_bits, dtype = 0x7FC00000, jnp.float32
     tbits = es + mbits
-    sign_mask = 1 << (mbits + ebits)
+    mag_mask = (1 << (mbits + ebits)) - 1                 # all but the sign
     full_exp = ((1 << ebits) - 1) << mbits                # |Inf| bit pattern
     # saturation bounds as bit patterns (positive-float ordering is the
     # integer ordering, so the clamp runs in the integer domain)
     minpos_bits = (bias - fmt.max_scale) << mbits
     maxpos_bits = (bias + fmt.max_scale) << mbits
 
-    bits = lax.bitcast_convert_type(x, U)
-    sbit = bits & U(sign_mask)
-    mag = bits & U(sign_mask - 1)
+    bits = lax.bitcast_convert_type(x, I)
+    sbit = bits & I(~mag_mask)
+    mag = bits & I(mag_mask)
     # zero via the same FLOAT compare the codec runs: on FTZ backends
     # (XLA CPU/TPU) subnormals flush to zero in both paths, on non-FTZ
     # backends both clamp them up to minpos — bit-identical either way
     is_zero = x == 0
-    is_nar = mag >= U(full_exp)                           # ±Inf or NaN
-    m = jnp.clip(mag, U(minpos_bits), U(maxpos_bits))
+    is_nar = mag >= I(full_exp)                           # ±Inf or NaN
+    m = jnp.clip(mag, I(minpos_bits), I(maxpos_bits))
 
-    q = (m >> U(mbits)).astype(jnp.int32) - bias          # power-of-two scale
+    q = (m >> mbits).astype(jnp.int32) - bias             # power-of-two scale
     r = q >> es                                           # regime value
     # regime bit count, branchless: r>=0 → r+2; r<0 → 1-r == (~r)+2
     nr = (r ^ (r >> 31)) + 2
     drop = nr + (tbits - (n - 1))                         # == encode's shift
     if 2 + tbits - (n - 1) >= 1:          # narrow formats: drop >= 1 always
-        dropc = jnp.minimum(drop, tbits).astype(U)
+        dropc = jnp.minimum(drop, tbits).astype(I)
     else:
-        dropc = jnp.clip(drop, 1, tbits).astype(U)
+        dropc = jnp.clip(drop, 1, tbits).astype(I)
 
-    adj = m + U(1 << mbits)                               # bias+1 alignment
-    half_ulp = U(1) << (dropc - U(1))
+    adj = m + I(1 << mbits)                               # bias+1 alignment
+    half_ulp = I(1) << (dropc - I(1))
     # pure-regime patterns (drop >= tbits): the last kept bit is the
     # regime's low bit — 0 for r >= 0, 1 for r < 0 (r's sign bit)
     lsb = jnp.where(drop < tbits,
-                    (adj >> dropc) & U(1),
-                    ((r >> 31).astype(U)) & U(1))
-    rounded = (adj + (half_ulp - U(1)) + lsb) & ~((half_ulp << U(1)) - U(1))
-    out = rounded - U(1 << mbits)
+                    (adj >> dropc) & I(1),
+                    ((r >> 31).astype(I)) & I(1))
+    rounded = (adj + (half_ulp - I(1)) + lsb) & ~((half_ulp << I(1)) - I(1))
+    out = rounded - I(1 << mbits)
     if 2 + tbits - (n - 1) < 1:                           # only wide posits
         out = jnp.where(drop >= 1, out, m)                # can be exact
     out = out | sbit
-    out = jnp.where(is_zero, U(0), out)
-    out = jnp.where(is_nar, U(nan_bits), out)
+    out = jnp.where(is_zero, I(0), out)
+    out = jnp.where(is_nar, I(nan_bits), out)
     return lax.bitcast_convert_type(out, dtype)
 
 

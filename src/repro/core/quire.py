@@ -21,7 +21,7 @@ Exactness envelope (per posit⟨n,es⟩; significand = n−2−es bits):
   product through ``two_prod`` (Dekker; no FMA required).
 * **Range.** A product's scale reaches 2·max_scale: ±112 for posit16 (fits
   f32), ±176/±240 for posit24/32 — the wide posits REQUIRE the f64
-  accumulator, i.e. x64 mode (``repro.compat.enable_x64``).
+  accumulator, i.e. x64 mode (``jax.enable_x64``).
   ``quire_acc_dtype`` resolves this per format.
 * **Accumulation.** The pairwise ``(s, c)`` tree is not literally exact for
   adversarial chains (the compensation term itself rounds), but its error

@@ -15,8 +15,8 @@ from typing import Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
-from repro.compat import device_mesh
 
 from .sharding import MeshInfo
 
@@ -53,7 +53,7 @@ def remesh(devices: Optional[List] = None,
     devices = devices if devices is not None else jax.devices()
     dp, tp = largest_valid_mesh(len(devices), cfg)
     arr = np.asarray(devices[: dp * tp]).reshape(dp, tp)
-    mesh = device_mesh(arr, ("data", "model"))
+    mesh = Mesh(arr, ("data", "model"))
     return MeshInfo(mesh, dp_axes=("data",))
 
 

@@ -85,9 +85,9 @@ def params_shardings(minfo: MeshInfo, params_like) -> Any:
 
 
 def _first_fit_cache_spec(shape, minfo: MeshInfo) -> P:
-    """Caches: dp on the first divisible dim (batch; falls back to the
-    sequence dim for batch=1 long-context cells); tp on the LAST divisible
-    dim (head_dim / state features).
+    """Caches: dp on the first divisible dim (batch; for batch=1 cells it
+    falls through to the kv-head, then the sequence dim of the head-major
+    KV layout); tp on the LAST divisible dim (head_dim / state features).
 
     Perf note (§Perf iteration 1): tp must NOT land on the cache's sequence
     dim — decode writes one token at a dynamic index, and a dynamic-update-

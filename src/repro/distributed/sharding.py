@@ -25,9 +25,9 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)

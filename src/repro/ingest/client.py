@@ -64,6 +64,8 @@ class ClientStats:
     partitions: int = 0           # injected partitions (chaos hook)
     corrupted_frames: int = 0     # injected corruptions (chaos hook)
     credit_waits: int = 0         # sends that waited on the credit window
+    unconfirmed_closes: int = 0   # closes that gave up waiting for the
+                                  # server to finish reading
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -75,13 +77,19 @@ class ReplayingClient:
                  auth_secret: Optional[str] = None,
                  replay_budget_bytes: int = 64 << 20,
                  connect_attempts: int = 80, backoff_s: float = 0.02,
-                 max_backoff_s: float = 1.0, ack_timeout_s: float = 2.0):
+                 max_backoff_s: float = 1.0, ack_timeout_s: float = 2.0,
+                 close_timeout_s: float = 60.0):
         """``lookup`` returns the patient's current ``(host, port)`` or
         ``None`` while unpublished (mid-failover); it is re-consulted on
         every connect attempt.  ``flow_control=False`` sends without
         credit pacing or barrier waits — pair it with a server started
         ``ack=False`` for the PR-4 wire behaviour (the overhead A/B's
         baseline arm); the reader still drains anything the server sends.
+        ``close_timeout_s`` bounds how long a close waits for the server
+        to read everything sent and close its side: a server busy in a
+        long jit compile must be waited for, since closing earlier lets
+        the ACKs it then writes be answered by a reset that discards the
+        frames it has not read yet.
         """
         self.patient = patient
         self.task = task
@@ -93,6 +101,7 @@ class ReplayingClient:
         self.backoff_s = float(backoff_s)
         self.max_backoff_s = float(max_backoff_s)
         self.ack_timeout_s = float(ack_timeout_s)
+        self.close_timeout_s = float(close_timeout_s)
         self.stats = ClientStats()
         self.corrupt_next = False      # chaos hook: corrupt the next send
         self.evicted: Optional[str] = None   # server close notice reason
@@ -358,8 +367,11 @@ class ReplayingClient:
                 if self._reader_task is not None:
                     try:
                         await asyncio.wait_for(
-                            asyncio.shield(self._reader_task), 5.0)
-                    except (asyncio.TimeoutError, Exception):
+                            asyncio.shield(self._reader_task),
+                            self.close_timeout_s)
+                    except asyncio.TimeoutError:
+                        self.stats.unconfirmed_closes += 1
+                    except Exception:
                         pass
             await self._teardown()
 

@@ -240,6 +240,7 @@ class FleetSimulator:
                       ledger=None,
                       clients_out: Optional[Dict[str,
                                                  ReplayingClient]] = None,
+                      close_timeout_s: float = 60.0,
                       ) -> None:
         """One ``ReplayingClient`` per patient against a live
         ``IngestServer``.
@@ -260,7 +261,9 @@ class FleetSimulator:
         ``stats_out``/``clients_out`` collect per-patient delivery stats
         and the live clients (the pool parks finished clients there for
         failover re-delivery); ``ledger`` records each client's
-        ``replayed_frames`` into the transport column.
+        ``replayed_frames`` into the transport column.  ``close_timeout_s``
+        bounds each client's wait, at a close, for the server to finish
+        reading its stream (``ReplayingClient``).
         """
         rng = np.random.default_rng(arrival_seed)
         plans = self.plans if plans is None else list(plans)
@@ -272,7 +275,8 @@ class FleetSimulator:
                     else (lambda p=plan.patient: lookup(p)))
             cli = ReplayingClient(plan.patient, plan.task, find,
                                   flow_control=flow_control,
-                                  auth_secret=auth_secret)
+                                  auth_secret=auth_secret,
+                                  close_timeout_s=close_timeout_s)
             if clients_out is not None:
                 clients_out[plan.patient] = cli
             part_at = (chaos.partition_after_frames

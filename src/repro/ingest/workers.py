@@ -39,9 +39,12 @@ per-restart recovery latency, and the clients' replay/reconnect counters
 under ``recovery``.
 
 Workers are spawned (never forked): a forked child would inherit the
-parent's initialized XLA runtime, and ``--xla_force_host_platform_device_
-count`` must be set before the child's first jax import, which is exactly
-what ``spawn`` + the env hook here guarantees.
+parent's initialized XLA runtime, and a worker's devices — its own TPU
+chip, or ``--xla_force_host_platform_device_count`` on the CPU — must be
+chosen before the child's first jax import, which is exactly what
+``spawn`` + the environment it starts the child with guarantees.  That
+environment also carries the parent's ``JAX_COMPILATION_CACHE_DIR``, so
+workers share its compile cache.
 
 Determinism: a worker builds its pipelines from the same seeds as the
 parent (the reference forest is retrained per process, bit-identically), so
@@ -66,6 +69,9 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.distributed.fault_tolerance import RestartPolicy
+from repro.launch.process import (backend_started, environ,
+                                  force_host_devices, tpu_chip_env,
+                                  tpu_chips_on_host)
 
 from .simulator import ChaosPlan, FleetSimulator, PatientPlan
 
@@ -82,6 +88,7 @@ class WorkerConfig:
     pins: Tuple[Tuple[str, str], ...]   # (patient, fmt) router pins
     n_patients: int = 0                 # sessions to expect before draining
     devices: int = 0                    # forced host devices (0 = inherit)
+    chip: Optional[int] = None          # TPU chip this worker owns
     max_batch: int = 32
     pad_policy: str = "max"
     stall_timeout_s: float = 1.5
@@ -101,14 +108,16 @@ class WorkerConfig:
     heartbeat_s: float = 0.25           # liveness beacon over the mp pipe
 
 
-def _worker_env(cfg: WorkerConfig) -> None:
-    """Set the XLA device split BEFORE the first jax import in this
-    process.  Appends to any inherited XLA_FLAGS rather than clobbering."""
-    if cfg.devices > 1:
-        flag = f"--xla_force_host_platform_device_count={cfg.devices}"
-        prev = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in prev:
-            os.environ["XLA_FLAGS"] = (prev + " " + flag).strip()
+def _check_devices(cfg: WorkerConfig) -> None:
+    """A chip-bound worker runs on exactly its chip, or not at all."""
+    if cfg.chip is None:
+        return
+    import jax
+    devs = jax.devices()
+    if len(devs) != 1 or devs[0].platform != "tpu":
+        raise RuntimeError(
+            f"worker {cfg.worker_id} bound to TPU chip {cfg.chip} sees "
+            f"{devs}, not one TPU chip")
 
 
 def _build_engine(cfg: WorkerConfig):
@@ -191,7 +200,7 @@ def worker_main(cfg: WorkerConfig, conn) -> None:
     event loop is blocked — a silent pipe means *hung*, not just busy),
     then ``("result", payload)`` or ``("error", repr)`` before exit.
     """
-    _worker_env(cfg)
+    force_host_devices(cfg.devices)   # before the first backend starts
     send_lock = threading.Lock()
 
     def send(msg) -> None:
@@ -213,6 +222,7 @@ def worker_main(cfg: WorkerConfig, conn) -> None:
         from repro.ingest import IngestServer, SessionManager, Supervisor
         from repro.ingest.spill import ResultSpill
 
+        _check_devices(cfg)
         engine = _build_engine(cfg)
         sessions = SessionManager(engine,
                                   stall_timeout_s=cfg.stall_timeout_s)
@@ -443,7 +453,10 @@ def _spawn(ctx, w: _Worker) -> None:
     cfg = dataclasses.replace(w.cfg, epoch=w.epoch)
     parent, child = ctx.Pipe()
     proc = ctx.Process(target=worker_main, args=(cfg, child), daemon=True)
-    proc.start()
+    # a chip-bound worker is born with its chip: JAX reads the platform
+    # when the child imports it, before worker_main runs
+    with environ({} if cfg.chip is None else tpu_chip_env(cfg.chip)):
+        proc.start()
     child.close()
     w.proc, w.conn = proc, parent
     w.port = None
@@ -617,10 +630,13 @@ def run_worker_fleet(sim: FleetSimulator, n_workers: int, *,
 
     Each worker gets a disjoint patient subset; ``ReplayingClient``s
     connect to the worker owning their patient through a live lookup that
-    follows failover respawns.  ``devices > 1`` additionally shards each
-    worker's dispatch over a forced host device split — processes ×
-    devices is the full fleet topology.  ``chaos`` injects the fault
-    schedule (worker kill, connection partitions, frame corruption,
+    follows failover respawns.  On a TPU host each worker owns one chip
+    (worker i binds chip i before its first JAX import; more workers than
+    chips, or a parent that already holds the chips, is refused).  Off
+    the TPU, ``devices > 1`` additionally shards each worker's dispatch
+    over a forced host device split — processes × devices is the full
+    fleet topology.  ``chaos`` injects the fault schedule (worker kill,
+    connection partitions, frame corruption,
     consumer stall); recovery events are counted in the parent registry
     (``worker_restarts_total``) and merged into the rollup ``metrics``.
     Raises only if EVERY worker failed; partial failures are surfaced in
@@ -628,6 +644,19 @@ def run_worker_fleet(sim: FleetSimulator, n_workers: int, *,
     """
     if n_workers < 1:
         raise ValueError(f"need ≥ 1 worker, got {n_workers}")
+    chips = tpu_chips_on_host()
+    if chips:
+        # a chip belongs to one process: one worker per chip, and the
+        # parent must not hold them
+        if n_workers > chips:
+            raise ValueError(f"{n_workers} workers for {chips} TPU chips: "
+                             "each worker owns one chip")
+        if devices > 1:
+            raise ValueError("devices > 1 splits the host CPU; a TPU "
+                             "worker owns exactly one chip")
+        if backend_started():
+            raise RuntimeError("this process has started a JAX backend and "
+                               "holds the TPU chips its workers need")
     from repro.obs import MetricsRegistry, merge_snapshots
     policy = restart_policy or RestartPolicy()
     chaos = chaos or ChaosPlan()
@@ -646,7 +675,8 @@ def run_worker_fleet(sim: FleetSimulator, n_workers: int, *,
                             if p.fmt is not None))
         cfg = WorkerConfig(
             worker_id=wid, tasks=tasks, pins=pins, n_patients=len(plans),
-            devices=devices, max_batch=max_batch, pad_policy=pad_policy,
+            devices=devices, chip=wid if chips else None,
+            max_batch=max_batch, pad_policy=pad_policy,
             stall_timeout_s=stall_timeout_s, scrape=scrape,
             supervisor_capacity=supervisor_capacity, ack=ack,
             auth_secret=auth_secret, spill_dir=spill_dir,
@@ -690,7 +720,8 @@ def run_worker_fleet(sim: FleetSimulator, n_workers: int, *,
                             plans=w.plans, lookup=_make_lookup(w),
                             flow_control=flow_control,
                             auth_secret=auth_secret, chaos=chaos,
-                            stats_out=stats, clients_out=clients)
+                            stats_out=stats, clients_out=clients,
+                            close_timeout_s=drain_timeout_s)
                     except (ConnectionError, OSError):
                         pass    # worker failed permanently mid-drive:
                                 # surfaced via failed_workers below

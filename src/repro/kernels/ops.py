@@ -1,27 +1,22 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On non-TPU backends (this CPU container) the kernels execute in
-``interpret=True`` mode — same kernel body, Python-evaluated — so the whole
-framework remains runnable and testable off-TPU.
+On the CPU backend the kernels execute in ``interpret=True`` mode — same
+kernel body, Python-evaluated — so the whole framework remains runnable and
+testable off-TPU (``common.interpret_mode``).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.formats import PositFormat
 
+from .common import interpret_mode
 from .posit_decode import posit_decode_2d
 from .posit_encode import posit_encode_2d
 from .posit_matmul import posit_matmul, rounded_matmul
 from .posit_round import posit_butterfly
-from .posit_kv_attention import posit_kv_attention
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .posit_kv_attention import posit_kv_attention_batched
 
 
 def decode(bits: jax.Array, fmt: PositFormat, out_dtype=jnp.float32):
@@ -35,7 +30,7 @@ def decode(bits: jax.Array, fmt: PositFormat, out_dtype=jnp.float32):
     mat = flat.reshape(-1, 128)
     out = posit_decode_2d(mat, fmt, out_dtype,
                           block_rows=min(512, mat.shape[0]),
-                          interpret=_interpret())
+                          interpret=interpret_mode())
     return out.reshape(-1)[:n].reshape(shape)
 
 
@@ -48,28 +43,29 @@ def encode(x: jax.Array, fmt: PositFormat):
         flat = jnp.pad(flat, (0, pad))
     mat = flat.reshape(-1, 128)
     out = posit_encode_2d(mat, fmt, block_rows=min(512, mat.shape[0]),
-                          interpret=_interpret())
+                          interpret=interpret_mode())
     return out.reshape(-1)[:n].reshape(shape)
 
 
 def matmul(a_bits: jax.Array, b_bits: jax.Array, fmt: PositFormat, **kw):
-    return posit_matmul(a_bits, b_bits, fmt, interpret=_interpret(), **kw)
+    return posit_matmul(a_bits, b_bits, fmt, interpret=interpret_mode(), **kw)
 
 
 def matmul_rounded(a: jax.Array, b: jax.Array, fmt: PositFormat, **kw):
     """Fused round_fmt(a·b) on float values (the Arith.matmul quire path)."""
-    return rounded_matmul(a, b, fmt, interpret=_interpret(), **kw)
+    return rounded_matmul(a, b, fmt, interpret=interpret_mode(), **kw)
 
 
 def butterfly(e_re, e_im, o_re, o_im, w_re, w_im, fmt: PositFormat):
     """One fused rounded radix-2 butterfly over whole broadcastable planes."""
     return posit_butterfly(e_re, e_im, o_re, o_im, w_re, w_im, fmt,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
 
 
 def kv_attention(q: jax.Array, k_bits: jax.Array, v_bits: jax.Array,
                  length, fmt: PositFormat, bs: int = 512):
-    """Batched wrapper: q (B, KV, G, D); k/v bits (B, S, KV, D).
+    """Batched wrapper: q (B, KV, G, D); k/v bits head-major (B, KV, S, D),
+    the ``KVCache`` storage layout, so each kernel block ends in (S, D).
 
     ``length`` is a scalar shared by every row or a (B,) vector of per-row
     valid lengths — the serving engine's continuous-batching slots each
@@ -77,13 +73,5 @@ def kv_attention(q: jax.Array, k_bits: jax.Array, v_bits: jax.Array,
     """
     B = q.shape[0]
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
-
-    def per_item(qb, kb, vb, lb):
-        def per_head(qh, kh, vh):
-            return posit_kv_attention(qh, kh, vh, lb, fmt, bs=bs,
-                                      interpret=_interpret())
-
-        return jax.vmap(per_head, in_axes=(0, 1, 1))(qb, kb, vb)
-
-    return jax.vmap(per_item, in_axes=(0, 0, 0, 0))(q, k_bits, v_bits,
-                                                    length)
+    return posit_kv_attention_batched(q, k_bits, v_bits, length, fmt, bs=bs,
+                                      interpret=interpret_mode())

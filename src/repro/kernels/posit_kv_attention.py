@@ -1,12 +1,13 @@
 """Pallas TPU kernel: decode-step attention against a posit-quantized KV
 cache — the memory-bound hot spot of the decode_32k / long_500k cells.
 
-For one kv-head group: q (G, D) attends over K/V stored as posit bits
-(S, D). The kernel streams S in blocks, decodes K/V tiles in VMEM, and keeps
-an online-softmax carry — HBM traffic is 2·S·D narrow integers instead of
-bf16/f32, cutting the dominant roofline term by the storage ratio.
+For each (row, kv-head group): q (G, D) attends over K/V stored as posit
+bits (S, D). The kernel streams S in blocks, decodes K/V tiles in VMEM, and
+keeps an online-softmax carry — HBM traffic is 2·S·D narrow integers
+instead of bf16/f32, cutting the dominant roofline term by the storage
+ratio.
 
-Grid: (S // bs,); carries live in VMEM scratch.
+Grid: (B, KV, S // bs); carries live in VMEM scratch.
 """
 from __future__ import annotations
 
@@ -35,9 +36,9 @@ def _block_plan(S: int, bs: int):
     return bs, -(-S // bs) * bs
 
 
-def _kv_attn_kernel(q_ref, kbits_ref, vbits_ref, len_ref, out_ref,
+def _kv_attn_kernel(len_ref, q_ref, kbits_ref, vbits_ref, out_ref,
                     m_ref, l_ref, acc_ref, *, fmt: PositFormat, bs: int):
-    i = pl.program_id(0)
+    i = pl.program_id(2)
 
     @pl.when(i == 0)
     def _init():
@@ -54,7 +55,7 @@ def _kv_attn_kernel(q_ref, kbits_ref, vbits_ref, len_ref, out_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * (D ** -0.5)  # (G, bs)
     pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < len_ref[0]
+    valid = pos < len_ref[pl.program_id(0)]
     logits = jnp.where(valid, logits, NEG_INF)
 
     m_prev = m_ref[...]                             # (G, 1)
@@ -71,51 +72,63 @@ def _kv_attn_kernel(q_ref, kbits_ref, vbits_ref, len_ref, out_ref,
     l_ref[...] = l_new
     acc_ref[...] = acc_new
 
-    @pl.when(i == pl.num_programs(0) - 1)
+    @pl.when(i == pl.num_programs(2) - 1)
     def _finish():
         out_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("fmt", "bs", "interpret"))
-def posit_kv_attention(q: jax.Array, k_bits: jax.Array, v_bits: jax.Array,
-                       length: jax.Array, fmt: PositFormat, bs: int = 512,
-                       interpret: bool = False) -> jax.Array:
-    """q: (G, D); k_bits/v_bits: (S, D) posit patterns; length: valid S.
+def posit_kv_attention_batched(q: jax.Array, k_bits: jax.Array,
+                               v_bits: jax.Array, lengths: jax.Array,
+                               fmt: PositFormat, bs: int = 512,
+                               interpret: bool = False) -> jax.Array:
+    """q: (B, KV, G, D); k_bits/v_bits: (B, KV, S, D) head-major posit
+    patterns; lengths: (B,) valid positions per row.  Returns (B, KV, G, D)
+    f32.
 
-    Returns (G, D) f32 attention output for one kv head. Batch/head axes are
-    mapped with vmap in ops.py.  S needs no relation to ``bs``: the sequence
-    is padded internally to a whole number of blocks (zero bit-patterns,
-    masked out by the ``pos < length`` guard).  S == 0 — and, via that same
-    mask, length == 0 — return all-zeros rather than launching a kernel.
+    One grid step per (row, kv head, sequence block), the block axis last
+    so the online-softmax carry in VMEM scratch runs in order.  Every
+    block keeps (rows, D) as its last two dims — the tiling Mosaic
+    accepts for f32, int16 and int8 alike.  S needs no relation to ``bs``:
+    the sequence is padded internally to a whole number of blocks (zero
+    bit-patterns, masked out by the ``pos < length`` guard).  S == 0 —
+    and, via that same mask, length == 0 — return all-zeros.
     """
-    G, D = q.shape
-    S, D2 = k_bits.shape
-    assert D == D2
+    B, KV, G, D = q.shape
+    S = k_bits.shape[2]
+    assert k_bits.shape == v_bits.shape == (B, KV, S, D), k_bits.shape
     q = q.astype(jnp.float32)
     if S == 0:
-        return jnp.zeros((G, D), jnp.float32)
+        return jnp.zeros((B, KV, G, D), jnp.float32)
     bs, S_pad = _block_plan(S, bs)
     if S_pad != S:
-        k_bits = jnp.pad(k_bits, ((0, S_pad - S), (0, 0)))
-        v_bits = jnp.pad(v_bits, ((0, S_pad - S), (0, 0)))
-    grid = (S_pad // bs,)
+        pad = ((0, 0), (0, 0), (0, S_pad - S), (0, 0))
+        k_bits = jnp.pad(k_bits, pad)
+        v_bits = jnp.pad(v_bits, pad)
+    head = pl.BlockSpec((None, None, G, D), lambda b, h, i: (b, h, 0, 0))
+    kv = pl.BlockSpec((None, None, bs, D), lambda b, h, i: (b, h, i, 0))
     return pl.pallas_call(
         functools.partial(_kv_attn_kernel, fmt=fmt, bs=bs),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((G, D), lambda i: (0, 0)),
-            pl.BlockSpec((bs, D), lambda i: (i, 0)),
-            pl.BlockSpec((bs, D), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((G, D), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, D), jnp.float32),
+        grid=(B, KV, S_pad // bs),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), head, kv, kv],
+        out_specs=head,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k_bits, v_bits,
-      jnp.minimum(length.reshape(1).astype(jnp.int32), S))
+    )(jnp.minimum(lengths.astype(jnp.int32), S), q, k_bits, v_bits)
+
+
+def posit_kv_attention(q: jax.Array, k_bits: jax.Array, v_bits: jax.Array,
+                       length: jax.Array, fmt: PositFormat, bs: int = 512,
+                       interpret: bool = False) -> jax.Array:
+    """One kv head: q (G, D); k_bits/v_bits (S, D); length: valid S.
+    Returns (G, D) f32 — the batched kernel on a (1, 1) grid prefix."""
+    out = posit_kv_attention_batched(
+        q[None, None], k_bits[None, None], v_bits[None, None],
+        jnp.reshape(length, (1,)), fmt, bs=bs, interpret=interpret)
+    return out[0, 0]

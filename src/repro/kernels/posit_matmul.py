@@ -35,7 +35,7 @@ from jax.experimental import pallas as pl
 from repro.core.formats import PositFormat
 from repro.core.posit import round_posit_math
 
-from .common import decode_tile
+from .common import decode_tile, interpret_mode
 
 
 def _matmul_kernel(a_ref, b_ref, out_ref, *, fmt: PositFormat,
@@ -129,7 +129,7 @@ def rounded_matmul(a: jax.Array, b: jax.Array, fmt: PositFormat,
     up to whole 256-blocks.  Padded rows/columns are sliced away.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     M, K = a.shape
     _, N = b.shape
     if M == 0 or N == 0:

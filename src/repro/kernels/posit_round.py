@@ -13,8 +13,8 @@ one kernel launch instead of a dispatch chain:
   elementary op rounded, the §VI-B hot loop of the cough pipeline:
   t = w ⊗ o (4 mul + 2 add, each rounded), u = e + t, v = e − t.
 
-On non-TPU backends the kernels run in ``interpret=True`` mode — same
-kernel body — so CPU containers stay testable; ``repro.core.arith`` routes
+On the CPU backend the kernels run in ``interpret=True`` mode — same
+kernel body — so CPU hosts stay testable; ``repro.core.arith`` routes
 through these kernels only when the backend profits from them (TPU), via
 the ``REPRO_ROUND_BACKEND`` switch.
 
@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from repro.core.formats import PositFormat
 from repro.core.posit import round_posit_math
 
-from .common import pad_to_tiles as _pad_2d
+from .common import interpret_mode, pad_to_tiles as _pad_2d
 
 
 def _round_kernel(x_ref, out_ref, *, fmt: PositFormat):
@@ -126,7 +126,7 @@ def posit_round(x: jax.Array, fmt: PositFormat,
                 interpret: bool | None = None) -> jax.Array:
     """Arbitrary-shape fused round (reshaped onto (rows, 128) tiles)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     mat, n, bm = _pad_2d(x)
     out = posit_round_2d(mat, fmt, block_rows=bm, interpret=interpret)
     return out.reshape(-1)[:n].reshape(x.shape)
@@ -137,7 +137,7 @@ def posit_fma_round(a: jax.Array, b: jax.Array, c: jax.Array,
                     interpret: bool | None = None) -> jax.Array:
     """Arbitrary-shape fused round(a·b + c) (broadcasts like jnp)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     a, b, c = jnp.broadcast_arrays(jnp.asarray(a), jnp.asarray(b),
                                    jnp.asarray(c))
     am, n, bm = _pad_2d(a)
@@ -160,7 +160,7 @@ def posit_butterfly(e_re, e_im, o_re, o_im, w_re, w_im, fmt: PositFormat,
     elementwise, so real lanes are unaffected.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     arrs = jnp.broadcast_arrays(e_re, e_im, o_re, o_im, w_re, w_im)
     shape = arrs[0].shape
     mats, n, bm = [], None, None

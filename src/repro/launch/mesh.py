@@ -7,9 +7,15 @@ Multi-pod:   (pod=2, data=16, model=16) = 512 chips
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import device_mesh, make_mesh
 from repro.distributed.sharding import MeshInfo
+
+
+def _make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes (its default is Explicit): the
+    model code places its shardings with constraints, not mesh types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) == n:
-        return make_mesh(shape, axes)
+        return _make_mesh(shape, axes)
     if len(devs) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}; have {len(devs)} — either "
@@ -28,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             ") or build a host-sized mesh with make_fleet_mesh_info()")
     # placeholder-device container has 512; single-pod uses the first 256
     arr = np.asarray(devs[:n]).reshape(shape)
-    return device_mesh(arr, axes)
+    return Mesh(arr, axes)
 
 
 def make_fleet_mesh_info(n_data: int = None) -> MeshInfo:
@@ -52,7 +58,7 @@ def make_fleet_mesh_info(n_data: int = None) -> MeshInfo:
             f"n_data={n} exceeds the {avail} visible devices — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count before the "
             "first jax call to split the host CPU")
-    mesh = make_mesh((n,), ("data",))
+    mesh = _make_mesh((n,), ("data",))
     return MeshInfo(mesh, dp_axes=("data",))
 
 
@@ -63,5 +69,5 @@ def make_mesh_info(*, multi_pod: bool = False) -> MeshInfo:
 
 
 def make_debug_mesh_info(n_data: int = 1, n_model: int = 1) -> MeshInfo:
-    mesh = make_mesh((n_data, n_model), ("data", "model"))
+    mesh = _make_mesh((n_data, n_model), ("data", "model"))
     return MeshInfo(mesh, dp_axes=("data",))

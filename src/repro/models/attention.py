@@ -37,9 +37,13 @@ class KVCache:
     static-batch decode) or a (B,) vector of per-row valid lengths (the
     serving engine's continuous-batching slots, where each slot holds a
     different request at a different context depth).
+
+    Storage is head-major, (B, KV, S, D): the decode kernel
+    (``kernels.posit_kv_attention``) reads each (row, kv head) as one
+    contiguous (S, D) slab, straight from the cache.
     """
 
-    k: object  # jax.Array (B,S,KV,D) bf16  |  PositTensor bits
+    k: object  # jax.Array (B,KV,S,D) bf16  |  PositTensor bits
     v: object
     length: jax.Array  # int32 scalar | (B,): number of valid positions
 
@@ -53,7 +57,7 @@ class KVCache:
     @property
     def capacity(self) -> int:
         k = self.k.bits if isinstance(self.k, PositTensor) else self.k
-        return k.shape[1]
+        return k.shape[2]
 
     @property
     def per_row(self) -> bool:
@@ -63,7 +67,7 @@ class KVCache:
     @staticmethod
     def create(batch: int, capacity: int, kv_heads: int, head_dim: int,
                fmt: Optional[PositFormat] = None, per_row: bool = False):
-        shape = (batch, capacity, kv_heads, head_dim)
+        shape = (batch, kv_heads, capacity, head_dim)
         length = jnp.zeros((batch,) if per_row else (), jnp.int32)
         if fmt is None:
             z = jnp.zeros(shape, jnp.bfloat16)
@@ -75,6 +79,7 @@ class KVCache:
         )
 
     def read(self, dtype=jnp.bfloat16):
+        """Dequantized (k, v) in the storage layout, (B, KV, S, D)."""
         def rd(store):
             if isinstance(store, PositTensor):
                 return store.dequant(jnp.float32).astype(dtype)
@@ -83,6 +88,8 @@ class KVCache:
         return rd(self.k), rd(self.v)
 
     def _encode(self, store, new):
+        """(B, S_new, KV, D) values → head-major storage entries."""
+        new = jnp.swapaxes(new, 1, 2)
         if isinstance(store, PositTensor):
             scaled = new.astype(jnp.float32)
             if store.scale is not None:
@@ -117,12 +124,16 @@ class KVCache:
             enc = self._encode(store, new)
             raw = self._raw(store)
             if self.per_row and S_new == 1:
-                rows = jnp.arange(raw.shape[0])
-                out = raw.at[rows, self.length].set(enc[:, 0])
+                # a select, not a scatter: a scatter at per-row positions
+                # makes XLA:TPU hold the cache sequence-major and copy it
+                # to and from the kernel's head-major layout every step
+                at = (jnp.arange(raw.shape[2])[None, None, :, None]
+                      == self.length[:, None, None, None])
+                out = jnp.where(at, enc, raw)
             else:
                 idx = jnp.zeros((), jnp.int32) if self.per_row \
                     else self.length
-                out = jax.lax.dynamic_update_slice(raw, enc, (0, idx, 0, 0))
+                out = jax.lax.dynamic_update_slice(raw, enc, (0, 0, idx, 0))
             return self._wrap(store, out)
 
         if new_length is None:
@@ -136,24 +147,30 @@ class KVCache:
 # Core attention math
 # ---------------------------------------------------------------------------
 
-def plain_attention(q, k, v, *, causal, window, cap, q_offset=0, kv_len=None):
+def plain_attention(q, k, v, *, causal, window, cap, q_offset=0, kv_len=None,
+                    heads_major=False):
     """Reference/materialized path (short sequences, decode).
 
     ``q_offset`` and ``kv_len`` accept scalars (shared by every row) or
     (B,) vectors — per-row offsets/lengths are how ragged right-padded
     prompts and continuous-batching decode slots mask their own context.
+    k/v are (B, S, KV, D), or (B, KV, S, D) — the ``KVCache`` layout —
+    with ``heads_major``.
     """
     B, Sq, H, D = q.shape
-    KV = k.shape[2]
+    if heads_major:
+        KV, S, kv = k.shape[1], k.shape[2], "bksd"
+    else:
+        S, KV, kv = k.shape[1], k.shape[2], "bskd"
     G = H // KV
     qg = q.reshape(B, Sq, KV, G, D)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
+    logits = jnp.einsum(f"bqkgd,{kv}->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
     logits = logits * (D ** -0.5)
     logits = softcap(logits, cap)
     # (1|B, Sq) query positions vs (S,) key positions
     qpos = jnp.reshape(jnp.asarray(q_offset), (-1, 1)) + jnp.arange(Sq)
-    kpos = jnp.arange(k.shape[1])
+    kpos = jnp.arange(S)
     m = (qpos[:, :, None] - kpos[None, None, :]) < window
     if causal:
         m &= kpos[None, None, :] <= qpos[:, :, None]
@@ -162,7 +179,7 @@ def plain_attention(q, k, v, *, causal, window, cap, q_offset=0, kv_len=None):
                                                (-1, 1, 1))
     logits = jnp.where(m[:, None, None], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(v.dtype), v,
+    out = jnp.einsum(f"bkgqs,{kv}->bqkgd", w.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
@@ -349,10 +366,14 @@ def attention_decode(p, x, cfg, cache: KVCache, *, window=BIG_WINDOW):
             cache.k.bits, cache.v.bits, cache.length, cache.k.fmt)
         out = out.reshape(B, 1, KV * G, hd).astype(x.dtype)
     else:
-        k, v = cache.read(dtype=x.dtype)
+        # f32 operands, as in the kernel: exact for bf16 and posit
+        # entries, and a batched dot that contracts the head-major
+        # cache's sequence dim has no bf16 kernel on the CPU backend
+        k, v = cache.read(dtype=jnp.float32)
         out = plain_attention(
             q, k, v, causal=True, window=window, cap=cfg.attn_softcap,
-            q_offset=cache.length - S_new, kv_len=cache.length)
+            q_offset=cache.length - S_new, kv_len=cache.length,
+            heads_major=True)
     return dense(p["wo"], out.reshape(B, S_new, -1)), cache
 
 

@@ -17,10 +17,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.distributed.sharding import MeshInfo
 

@@ -46,6 +46,8 @@ class ServeConfig:
     temperature: float = 0.0     # 0 → greedy
     seed: int = 0                # engine PRNG root (folded with rid, step)
     max_completions: Optional[int] = 256  # drop-oldest completion backlog
+    keep_logits: bool = False    # host copy of every emitted token's logits
+                                 # row in ``served_logits[rid]``
 
 
 class _Lane:
@@ -79,7 +81,8 @@ class _Lane:
 
 def _make_decode_step(model):
     """One fused device step: decode_step + per-row key derivation +
-    temperature/greedy sampling + length freeze of inactive rows."""
+    temperature/greedy sampling + length freeze of inactive rows.  Returns
+    (next tokens, caches, the (B, vocab) f32 logits sampled from)."""
     vocab = model.cfg.vocab
 
     def fn(params, cur, caches, base_key, rids, steps, temps, active):
@@ -102,7 +105,7 @@ def _make_decode_step(model):
                                  jnp.where(active, a.length, b.length)),
             caches, new_caches,
             is_leaf=lambda x: isinstance(x, KVCache))
-        return nxt, new_caches
+        return nxt, new_caches, lv
 
     return jax.jit(fn)
 
@@ -141,6 +144,8 @@ class ServingEngine:
         self.scheduler = Scheduler(cfg.batch_size, cfg.max_completions,
                                    metrics=self.metrics)
         self.ledger = TokenLedger()
+        # rid → logits rows of its emitted tokens (``cfg.keep_logits``)
+        self.served_logits: Dict[int, List[np.ndarray]] = {}
 
     # -- params -----------------------------------------------------------
     def _params_for(self, weights_fmt: Optional[str]):
@@ -206,6 +211,8 @@ class ServingEngine:
         # first token comes from the prefill logits (step 0 of the key
         # stream for this request)
         lv = logits[0, -1, :self.model.cfg.vocab].astype(jnp.float32)
+        if self.cfg.keep_logits:
+            self.served_logits[req.rid] = [np.asarray(lv)]
         if req.temperature > 0:
             key = jax.random.fold_in(
                 jax.random.fold_in(self._base_key, req.rid), 0)
@@ -256,7 +263,7 @@ class ServingEngine:
             for i in rows:
                 lane.active[i] = True
             t0 = time.perf_counter()
-            nxt, lane.caches = lane._decode(
+            nxt, lane.caches, lv = lane._decode(
                 lane.params, lane.cur, lane.caches, self._base_key,
                 jnp.asarray(lane.rids), jnp.asarray(lane.steps),
                 jnp.asarray(lane.temps), jnp.asarray(lane.active))
@@ -264,6 +271,10 @@ class ServingEngine:
             wall = time.perf_counter() - t0
             lane.cur = nxt
             toks = np.asarray(nxt)
+            if self.cfg.keep_logits:
+                lv = np.asarray(lv)
+                for i in rows:
+                    self.served_logits[int(lane.rids[i])].append(lv[i])
             energy = 0.0
             kv_read = 0.0
             for i in rows:

@@ -13,9 +13,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.policy import QuantPolicy
 from repro.core.quant import fake_quant
 from repro.distributed.collectives import posit_all_reduce

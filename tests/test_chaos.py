@@ -249,6 +249,94 @@ def test_partition_and_corruption_recover_bit_identical():
 
 
 # ---------------------------------------------------------------------------
+# Clean close against a server whose event loop is blocked (a jit compile in
+# a read handler, as in a pool worker's first dispatch)
+# ---------------------------------------------------------------------------
+def _drive_blocked_server(block_s, n_patients=4, wait_closed=True,
+                          **tcp_kw):
+    """Serve from a second thread and event loop (a pool worker's view:
+    the clients' loop keeps running while the server's is blocked); the
+    first dispatch sleeps ``block_s`` on the server's loop.  Returns
+    (sim, engine, sessions, client stats)."""
+    import dataclasses
+    import threading
+    import time
+
+    sim = FleetSimulator(n_patients=n_patients, windows=2, seed=3,
+                         mixed=False, n_cough=0, ecg_chunk=(20, 40))
+    pipe = rpeak_pipeline()
+    blocked = []
+
+    def make_fn(fmt):
+        fn = pipe.make_fn(fmt)
+
+        def first_call_blocks(arrays):
+            if not blocked:
+                blocked.append(fmt)
+                time.sleep(block_s)
+            return fn(arrays)
+        return first_call_blocks
+
+    eng = StreamEngine({"rpeak": dataclasses.replace(pipe, make_fn=make_fn)},
+                       max_batch=1)
+    sm = SessionManager(eng, stall_timeout_s=120.0)
+    sim.pin_all(eng)
+    ready, stop, box = threading.Event(), threading.Event(), {}
+
+    def serve():
+        async def main():
+            # small reads: a stream spans many reads, each answered by ACKs
+            async with IngestServer(sm, port=0, ack=True,
+                                    read_bytes=512) as srv:
+                box["port"] = srv.port
+                ready.set()
+                while not stop.is_set():
+                    await asyncio.sleep(0.01)
+        asyncio.run(main())
+
+    th = threading.Thread(target=serve)
+    th.start()
+    stats = {}
+    try:
+        assert ready.wait(60.0)
+        asyncio.run(sim.run_tcp("127.0.0.1", box["port"], stats_out=stats,
+                                flow_control=False, **tcp_kw))
+        deadline = time.monotonic() + 60.0
+        while (wait_closed and not sm.all_closed()
+               and time.monotonic() < deadline):
+            time.sleep(0.02)
+    finally:
+        stop.set()
+        th.join()
+    eng.drain()
+    return sim, eng, sm, stats
+
+
+def test_close_waits_for_a_server_blocked_in_a_compile():
+    """The clients finish sending while the server is blocked for longer
+    than a close used to wait (5 s).  Closing then let the server's next
+    ACK draw a reset that discarded the frames it had not read: patients
+    lost windows and their sessions never closed.  A close now waits for
+    the server to read everything, so every stream arrives whole."""
+    sim, eng, sm, stats = _drive_blocked_server(6.0)
+    assert sm.all_closed(), sm.open_sessions()
+    assert sum(s.unconfirmed_closes for s in stats.values()) == 0
+    frames = sum(len(c) for p in sim.plans for c in p.chunks.values())
+    assert eng.ledger.transport_summary()["fleet"]["frames"] >= frames
+    for p in sim.plans:
+        assert eng.tracker_for(p.patient, "rpeak").peaks == \
+            _offline_prefix(p.signals["ecg"][0]), p.patient
+
+
+def test_close_timeout_is_bounded_and_counted():
+    """A close gives up after ``close_timeout_s`` (liveness against a hung
+    server) and counts each close it could not confirm."""
+    _, _, _, stats = _drive_blocked_server(1.0, wait_closed=False,
+                                           close_timeout_s=0.2)
+    assert sum(s.unconfirmed_closes for s in stats.values()) >= 1
+
+
+# ---------------------------------------------------------------------------
 # Worker-pool failover: drain-barrier timeout surfaces, kills recover
 # ---------------------------------------------------------------------------
 def test_supervise_drain_barrier_timeout_fails_worker():

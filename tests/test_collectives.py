@@ -8,12 +8,12 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.core.formats import POSIT16
     from repro.distributed.collectives import posit_all_reduce, posit_all_reduce_ef
 
-    mesh = make_mesh((8,), ("pod",))
+    mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, 64)).astype(np.float32))
 

@@ -81,8 +81,7 @@ def test_direct_round_sampled_grid_f32(fmt):
 @pytest.mark.parametrize("fmt", [POSIT16, POSIT24, POSIT32],
                          ids=lambda f: f.name)
 def test_direct_round_sampled_grid_f64(fmt):
-    from repro.compat import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(1)
         pats = rng.integers(0, 1 << fmt.n, size=50000, dtype=np.int64)
         lattice = np.asarray(decode(jnp.asarray(pats, jnp.int32), fmt,

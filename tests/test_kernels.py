@@ -73,15 +73,15 @@ def test_batched_kv_attention_wrapper():
     B, KV, G, D, S = 2, 2, 3, 128, 512
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.normal(size=(B, KV, G, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, KV, S, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, KV, S, D)), jnp.float32)
     k_bits, v_bits = ref.encode_ref(k, fmt), ref.encode_ref(v, fmt)
     out = ops.kv_attention(q, k_bits, v_bits, S, fmt, bs=256)
     assert out.shape == (B, KV, G, D)
     for b in range(B):
         for h in range(KV):
-            want = ref.kv_attention_ref(q[b, h], k_bits[b, :, h],
-                                        v_bits[b, :, h],
+            want = ref.kv_attention_ref(q[b, h], k_bits[b, h],
+                                        v_bits[b, h],
                                         jnp.asarray(S), fmt)
             np.testing.assert_allclose(np.asarray(out[b, h]),
                                        np.asarray(want), rtol=2e-5, atol=2e-5)
@@ -153,15 +153,15 @@ def test_batched_kv_attention_per_row_lengths():
     B, KV, G, D, S = 3, 2, 2, 64, 256
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.normal(size=(B, KV, G, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, KV, S, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, KV, S, D)), jnp.float32)
     k_bits, v_bits = ref.encode_ref(k, fmt), ref.encode_ref(v, fmt)
     lengths = jnp.asarray([256, 97, 5], jnp.int32)
     out = ops.kv_attention(q, k_bits, v_bits, lengths, fmt, bs=128)
     for b in range(B):
         for h in range(KV):
-            want = ref.kv_attention_ref(q[b, h], k_bits[b, :, h],
-                                        v_bits[b, :, h], lengths[b], fmt)
+            want = ref.kv_attention_ref(q[b, h], k_bits[b, h],
+                                        v_bits[b, h], lengths[b], fmt)
             np.testing.assert_allclose(np.asarray(out[b, h]),
                                        np.asarray(want),
                                        rtol=2e-5, atol=2e-5)

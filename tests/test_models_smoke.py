@@ -15,8 +15,9 @@ ARCHS = sorted(CONFIGS)
 
 
 def tiny_minfo():
-    from repro.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     return MeshInfo(mesh, dp_axes=("data",))
 
 

@@ -94,8 +94,7 @@ def test_roundtrip_exhaustive(fmt):
 
 
 def test_roundtrip_wide_formats_f64():
-    from repro.compat import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         for fmt in WIDE_FMTS:
             rng = np.random.default_rng(0)
             pats = rng.integers(0, 1 << fmt.n, size=20000, dtype=np.int64)

@@ -11,11 +11,11 @@ signed, the oracle returns unsigned ints, and e.g. posit8's NaR prints as
 import contextlib
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
 from repro.core.arith import Arith, backend_overrides, fusion_cache_key
 from repro.core.formats import POSIT_FORMATS
 from repro.core.posit import decode, encode
@@ -29,7 +29,7 @@ _WIDE = ("posit24", "posit32")
 
 
 def _ctx(name):
-    return enable_x64() if name in _WIDE else contextlib.nullcontext()
+    return jax.enable_x64() if name in _WIDE else contextlib.nullcontext()
 
 
 def _dtype(name):

@@ -16,8 +16,8 @@ from repro.roofline.analysis import collective_bytes, roofline_terms
 
 def minfo_2x4():
     # AbstractMesh: spec-level tests need axis sizes, not 8 real devices
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     return MeshInfo(mesh, dp_axes=("data",))
 
 

@@ -209,6 +209,25 @@ def test_engine_per_request_keys_do_not_replay(served_model):
     np.testing.assert_array_equal(a2, b2)
 
 
+def test_engine_keeps_the_logits_it_sampled_from(served_model):
+    """keep_logits: one logits row per emitted token, from the programs
+    that served it (the B=1 prefill, then the batched decode), and every
+    greedy token is the argmax of its own row."""
+    cfg, minfo, model, params = served_model
+    with minfo.mesh:
+        eng = ServingEngine(model, params,
+                            ServeConfig(batch_size=2, max_prompt=8,
+                                        max_new_tokens=4, keep_logits=True))
+        rids = [eng.submit(p) for p in _prompts(cfg, [3, 6, 8], seed=5)]
+        comps = {c.rid: c for c in eng.run()}
+    assert sorted(eng.served_logits) == sorted(rids)
+    for rid in rids:
+        rows = np.stack(eng.served_logits[rid])
+        assert rows.shape == (len(comps[rid].tokens), cfg.vocab)
+        assert np.isfinite(rows).all()
+        np.testing.assert_array_equal(rows.argmax(-1), comps[rid].tokens)
+
+
 def test_engine_eos_frees_slot(served_model):
     cfg, minfo, model, params = served_model
     with minfo.mesh:

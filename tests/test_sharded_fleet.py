@@ -161,7 +161,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.apps.cough import train_reference_forest
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed.collectives import ledger_psum
     from repro.ingest import FleetSimulator
     from repro.launch.mesh import make_fleet_mesh_info
