@@ -29,7 +29,7 @@ threshold itself runs in the window's routed arithmetic.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -260,12 +260,16 @@ class RPeakFold:
         across boundaries,
       * the RR estimate (bootstrapped from the first ``rr_boot`` candidates,
         then EMA-updated exactly as the paper's stage 4).
+
+    ``clock`` (default None) times each push's threshold round trip into
+    ``threshold_s``; without one the fold reads no clock.
     """
 
     def __init__(self, fs: int = ECG_FS,
                  reservoir_size: int = RESERVOIR_SIZE,
                  reservoir_stride: int = RESERVOIR_STRIDE,
-                 rr_boot: int = RR_BOOT, tail_max_s: float = TAIL_MAX_S):
+                 rr_boot: int = RR_BOOT, tail_max_s: float = TAIL_MAX_S,
+                 clock: Optional[Callable[[], float]] = None):
         self.fs = fs
         self.refractory = int(REFRACTORY_S * fs)
         self.reservoir_size = reservoir_size
@@ -285,6 +289,8 @@ class RPeakFold:
         self.rr: Optional[float] = None
         self.emitted = 0
         self.finalized = False
+        self.clock = clock
+        self.threshold_s: Optional[Tuple[float, float]] = None
 
     def push(self, ar: Arith, scores: np.ndarray,
              final: bool = False) -> np.ndarray:
@@ -303,8 +309,12 @@ class RPeakFold:
             self.reservoir = reservoir_update(
                 self.reservoir, s, self.reservoir_size,
                 self.reservoir_stride)
+            clock = self.clock
+            t_thr = clock() if clock is not None else 0.0
             self.thr, cents = threshold_update(ar, self.reservoir,
                                                init=self.cents)
+            if clock is not None:
+                self.threshold_s = (t_thr, clock())
             self.cents = cents if np.all(np.isfinite(cents)) else None
         self.tail = np.concatenate([self.tail, s])
         self.end += len(s)

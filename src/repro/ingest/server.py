@@ -225,7 +225,11 @@ class IngestServer:
                     # the flow-control plane: cumulative ACKs + credit for
                     # every frontier this chunk advanced, resume set +
                     # barrier for every HELLO it carried
-                    self.sessions.flush_acks()
+                    t_ack = tr.now() if tr is not None else 0.0
+                    acks = self.sessions.flush_acks()
+                    if tr is not None:
+                        tr.complete("frame", "flush_acks", t_ack, tr.now(),
+                                    track="ingest", args={"acks": acks})
                 waited = 0.0
                 while (self.sessions.dispatch_backlog()
                        > self.high_watermark):

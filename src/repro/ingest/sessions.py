@@ -104,9 +104,6 @@ class SessionManager:
         self._evicted_c = engine.metrics.counter(
             "ingest_evicted_notices_total",
             "EVICTED close notices, by reason and delivery")
-        self._acked_c = engine.metrics.counter(
-            "acked_frames_total",
-            "frames covered by cumulative ACKs sent to clients, by patient")
 
     # -- server→client notices ------------------------------------------------
     def register_sender(self, patient: str,
@@ -157,8 +154,6 @@ class SessionManager:
                         s.patient, s.task, mod, m.next_seq, credit)))
                 except Exception:
                     break    # client gone mid-flush: a reconnect re-acks
-                self._acked_c.inc(m.next_seq - max(m.acked_seq, 0),
-                                  patient=s.patient)
                 m.acked_seq = m.next_seq
                 sent += 1
             if s.ack_hello:
@@ -263,9 +258,6 @@ class SessionManager:
             return
         # in-order: deliver, then flush any now-contiguous held frames
         self.engine.ingest(s.patient, s.task, frame.modality, frame.payload)
-        if tr is not None:
-            tr.instant("session", "deliver", track=s.patient,
-                       args={"modality": frame.modality, "seq": seq})
         m.next_seq += 1
         while m.next_seq in m.held:
             payload, t_held = m.held.pop(m.next_seq)

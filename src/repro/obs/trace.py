@@ -4,36 +4,57 @@ The tracer is a host-side, monotonic-clock (``time.perf_counter``, the
 same clock that stamps ``Window.ready_wall``/``done_wall``) event log.
 It never runs inside jit: callers stamp timestamps around dispatches and
 record completed spans after the fact, so a disabled tracer is simply
-``None`` and the hot path pays one attribute load + ``is None`` test.
+``None`` and the hot path pays one attribute load + ``is None`` test —
+every site reads the clock, takes an id or builds ``args`` only behind
+that test.
 
 Memory is bounded: events land in a ring of ``capacity`` entries and the
 oldest are dropped (and counted in ``dropped``) when full — a soak can
 run forever with a live tracer without growing.
 
-Span taxonomy (categories, one per pipeline stage):
+Every event carries an integer id.  A span may name its parent's id —
+taken with ``new_id()`` before the parent's work starts, so that its
+children, which close first, can point at it — and a ``key`` shared by
+the spans of one window (``"patient/widx"``) or one request (the rid).
+``self_times`` subtracts from each span the part of it its children
+cover.
 
-========== =====================================================
-category   span
-========== =====================================================
-frame      wire bytes → decoded frames (per read, ingest server)
-reorder    out-of-order DATA held → released (per held frame)
-session    frame accepted by the session layer → samples delivered
-stage      window closed by the ring (``ready_wall``) → dispatch start
-dispatch   jit batch dispatch (``block_until_ready`` wall)
-drain      results popped by the supervisor
-serve      token serving: admit / prefill / decode / retire
-========== =====================================================
+Span taxonomy (``category/name``; children indented under their parent):
 
-Export is Chrome trace-event JSON (the ``{"traceEvents": [...]}`` shape)
-so ``stream_bench --trace out.json`` produces a file that opens directly
-in Perfetto / ``chrome://tracing``.
+=========================== ===============================================
+span                        covers
+=========================== ===============================================
+frame/decode                wire bytes → decoded frames (per socket read)
+frame/flush_acks            the ACK/credit walk after a read that delivered
+                            frames (``args["acks"]``: ACK frames sent)
+reorder/held                out-of-order DATA held → released
+dispatch/<task>/<fmt>       one engine dispatch, staging to appended results
+  dispatch/stage            the padded numpy batch is built
+  dispatch/device           jit call → outputs are numpy arrays on the host
+  dispatch/tracker          the per-patient trackers over the batch
+    dispatch/tracker.threshold  one window's 2-means round trip (key)
+  dispatch/account          ledger record, results appended
+drain/supervisor.poll       results popped by the supervisor
+serve/step                  one ``ServingEngine.step`` (admitted, rows)
+  serve/admit               one admission (key: rid)
+    serve/prefill           B=1 prefill, cache install, first token
+  serve/decode              one lane's batched decode, tokens on the host
+  serve/account             that lane's per-row energy, KV bytes, tokens
+serve/retire (instant)      a request finished
+=========================== ===============================================
+
+Export is Chrome trace-event JSON (the ``{"traceEvents": [...]}`` shape,
+id, parent and key in each event's ``args``) so ``stream_bench --trace
+out.json`` produces a file that opens directly in Perfetto /
+``chrome://tracing``.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Tracer"]
 
@@ -43,7 +64,11 @@ _INSTANT = "i"
 
 
 class Tracer:
-    """Bounded in-memory span log. All times are perf_counter seconds."""
+    """Bounded in-memory span log. All times are perf_counter seconds.
+
+    ``events()`` gives ``(ph, cat, name, start, end, track, args, id,
+    parent, key)`` tuples; fields 0-6 keep their places for readers that
+    unpack them by position."""
 
     def __init__(self, capacity: int = 1 << 16):
         if capacity <= 0:
@@ -52,11 +77,17 @@ class Tracer:
         self._events: deque = deque()
         self.dropped = 0
         self._t0 = time.perf_counter()
+        self._ids = itertools.count(1)
 
     # -- recording ---------------------------------------------------------
 
     def now(self) -> float:
         return time.perf_counter()
+
+    def new_id(self) -> int:
+        """A fresh span id: a parent takes its id before its work starts
+        and hands it to ``complete`` (``sid=``) once it ends."""
+        return next(self._ids)
 
     def _push(self, ev: Tuple) -> None:
         if len(self._events) >= self.capacity:
@@ -72,9 +103,18 @@ class Tracer:
         end_s: float,
         track: str = "main",
         args: Optional[Dict[str, Any]] = None,
-    ) -> None:
-        """Record a completed span [start_s, end_s] (perf_counter seconds)."""
-        self._push((_COMPLETE, cat, name, start_s, max(end_s, start_s), track, args))
+        *,
+        sid: Optional[int] = None,
+        parent: Optional[int] = None,
+        key: Any = None,
+    ) -> int:
+        """Record a completed span [start_s, end_s] (perf_counter seconds);
+        returns its id (``sid``, or a fresh one)."""
+        if sid is None:
+            sid = next(self._ids)
+        self._push((_COMPLETE, cat, name, start_s, max(end_s, start_s),
+                    track, args, sid, parent, key))
+        return sid
 
     def instant(
         self,
@@ -83,10 +123,16 @@ class Tracer:
         ts_s: Optional[float] = None,
         track: str = "main",
         args: Optional[Dict[str, Any]] = None,
-    ) -> None:
+        *,
+        parent: Optional[int] = None,
+        key: Any = None,
+    ) -> int:
         if ts_s is None:
             ts_s = time.perf_counter()
-        self._push((_INSTANT, cat, name, ts_s, ts_s, track, args))
+        sid = next(self._ids)
+        self._push((_INSTANT, cat, name, ts_s, ts_s, track, args, sid,
+                    parent, key))
+        return sid
 
     def reset(self) -> None:
         """Clear recorded events and re-zero the export epoch (a bench
@@ -106,6 +152,19 @@ class Tracer:
     def events(self) -> List[Tuple]:
         return list(self._events)
 
+    def self_times(self) -> Dict[int, float]:
+        """Each complete span's self time, by id: its duration less the
+        part of it that its children cover (children of one parent do not
+        overlap: they run one after another on the host)."""
+        spans = {ev[7]: ev for ev in self._events if ev[0] == _COMPLETE}
+        out = {sid: ev[4] - ev[3] for sid, ev in spans.items()}
+        for ev in spans.values():
+            par = spans.get(ev[8])
+            if par is not None:
+                out[ev[8]] -= max(0.0, min(ev[4], par[4])
+                                  - max(ev[3], par[3]))
+        return out
+
     # -- export ------------------------------------------------------------
 
     def _ts_us(self, t: float) -> float:
@@ -115,7 +174,8 @@ class Tracer:
         """Render the ring as a Chrome trace-event document."""
         tracks: Dict[str, int] = {}
         events: List[Dict[str, Any]] = []
-        for ph, cat, name, start, end, track, args in self._events:
+        for (ph, cat, name, start, end, track, args, sid, parent,
+             key) in self._events:
             tid = tracks.setdefault(track, len(tracks))
             ev: Dict[str, Any] = {
                 "name": name,
@@ -129,8 +189,11 @@ class Tracer:
                 ev["dur"] = max(0.0, (end - start) * 1e6)
             else:
                 ev["s"] = "t"
-            if args:
-                ev["args"] = dict(args)
+            ev["args"] = dict(args or {}, id=sid)
+            if parent is not None:
+                ev["args"]["parent"] = parent
+            if key is not None:
+                ev["args"]["key"] = key
             events.append(ev)
         meta = [
             {

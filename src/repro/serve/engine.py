@@ -183,7 +183,10 @@ class ServingEngine:
         return self.scheduler.submit(req)
 
     # -- admission: B=1 ragged prefill, install rows into the lane --------
-    def _admit(self, req: Request, slot: int) -> None:
+    def _admit(self, req: Request, slot: int,
+               span: Optional[int] = None) -> None:
+        """Prefill one admitted request into ``slot``; ``span`` is the id of
+        the ``serve/admit`` span its ``serve/prefill`` span belongs to."""
         lane = self._lane(req.policy)
         P = len(req.prompt)
         P_pad = bucket_size(P, self.cfg.max_prompt)
@@ -225,7 +228,8 @@ class ServingEngine:
             self.tracer.complete("serve", "prefill", t0, t1,
                                  track=f"lane:{req.policy.lane}",
                                  args={"rid": req.rid, "P": P,
-                                       "P_pad": P_pad, "slot": slot})
+                                       "P_pad": P_pad, "slot": slot},
+                                 parent=span, key=req.rid)
         self.ledger.record_prefill(
             req.policy.lane, P, t1 - t0,
             prefill_energy_nj(self.model.cfg, P, req.policy))
@@ -234,7 +238,8 @@ class ServingEngine:
             if self.tracer is not None:
                 self.tracer.instant("serve", "retire",
                                     track=f"lane:{req.policy.lane}",
-                                    args={"rid": req.rid, "slot": slot})
+                                    args={"rid": req.rid, "slot": slot},
+                                    parent=span, key=req.rid)
             return
         lane.cur = lane.cur.at[slot].set(tok)
         lane.rids[slot] = req.rid
@@ -248,13 +253,19 @@ class ServingEngine:
         """Admit what fits, then run one batched decode step per active
         lane.  Returns the number of real tokens emitted."""
         tr = self.tracer
-        for req, slot in self.scheduler.take_admissions():
-            t_adm = tr.now() if tr is not None else 0.0
-            self._admit(req, slot)
+        if tr is not None:
+            t_step, sid, decoded = tr.now(), tr.new_id(), {}
+        admissions = self.scheduler.take_admissions()
+        for req, slot in admissions:
+            adm = None
+            if tr is not None:
+                t_adm, adm = tr.now(), tr.new_id()
+            self._admit(req, slot, span=adm)
             if tr is not None:
                 tr.complete("serve", "admit", t_adm, tr.now(),
                             track=f"lane:{req.policy.lane}",
-                            args={"rid": req.rid, "slot": slot})
+                            args={"rid": req.rid, "slot": slot},
+                            sid=adm, parent=sid, key=req.rid)
         emitted = 0
         for lane_name in self.scheduler.active_lanes():
             lane = self._lanes[lane_name]
@@ -288,18 +299,27 @@ class ServingEngine:
                 if self.scheduler.on_token(lane_name, i, int(toks[i])):
                     lane.active[i] = False
                     if tr is not None:
+                        rid = int(lane.rids[i])
                         tr.instant("serve", "retire",
                                    track=f"lane:{lane_name}",
-                                   args={"rid": int(lane.rids[i]),
-                                         "slot": int(i)})
+                                   args={"rid": rid, "slot": int(i)},
+                                   parent=sid, key=rid)
             emitted += len(rows)
-            if tr is not None:
-                tr.complete("serve", "decode", t0, t0 + wall,
-                            track=f"lane:{lane_name}",
-                            args={"rows": len(rows)})
             self.ledger.record_decode(
                 lane_name, len(rows), self.cfg.batch_size - len(rows),
                 wall, energy, kv_read)
+            if tr is not None:
+                tr.complete("serve", "decode", t0, t0 + wall,
+                            track=f"lane:{lane_name}",
+                            args={"rows": len(rows)}, parent=sid)
+                tr.complete("serve", "account", t0 + wall, tr.now(),
+                            track=f"lane:{lane_name}",
+                            args={"rows": len(rows)}, parent=sid)
+                decoded[lane_name] = len(rows)
+        if tr is not None:
+            tr.complete("serve", "step", t_step, tr.now(), track="serve",
+                        args={"admitted": len(admissions),
+                              "rows": decoded}, sid=sid)
         return emitted
 
     def run(self) -> List[Completion]:

@@ -90,7 +90,10 @@ class WindowResult:
     t0_s: float
     outputs: Dict[str, np.ndarray]  # per-window slices of the batch outputs
     ready_wall: float = 0.0         # wall clock when the window became ready
-    done_wall: float = 0.0          # wall clock when its batch materialized
+    # wall clock once its batch's trackers and ledger row are done (later
+    # than the outputs' arrival on the host by the tracker's time); one
+    # stamp per dispatch
+    done_wall: float = 0.0
 
 
 class StreamEngine:
@@ -127,8 +130,9 @@ class StreamEngine:
         ``repro.obs.MetricsRegistry``; ``None`` creates a private one, and
         ``repro.obs.NULL_METRICS`` disables the plane at ~zero cost).  The
         session/supervisor/server layers share it.  ``tracer`` (a
-        ``repro.obs.Tracer``, default off) records per-window lifecycle
-        spans — both are host-side only and never enter jit.
+        ``repro.obs.Tracer``, default off) records each dispatch split
+        into stage, device, tracker (with each window's threshold round
+        trip) and account — both are host-side only and never enter jit.
 
         ``mesh_info`` (a ``repro.distributed.MeshInfo``, e.g. from
         ``launch.mesh.make_fleet_mesh_info``) shards every dispatch over the
@@ -337,6 +341,10 @@ class StreamEngine:
         return make_fleet_batch_fn(self._fn(task, fmt), self.mesh_info)
 
     def _dispatch(self, task: str, fmt: str, windows: List[Window]) -> None:
+        tr = self.tracer
+        trk_id = None
+        if tr is not None:
+            t_in, sid, trk_id = tr.now(), tr.new_id(), tr.new_id()
         pipe = self.pipelines[task]
         B = len(windows)
         Bpad = self.max_batch if self._effective_pad_to_max() \
@@ -367,7 +375,8 @@ class StreamEngine:
         # zero-copy views into these arrays
         outs = {k: np.asarray(jax.block_until_ready(v))
                 for k, v in outs.items()}
-        dt = time.perf_counter() - t0
+        t_dev = time.perf_counter()
+        dt = t_dev - t0
         if ledger_row is None:
             n_real, n_padded = B, Bpad - B
         else:
@@ -380,28 +389,34 @@ class StreamEngine:
                     f"host staged {B} (task={task!r}, fmt={fmt!r})")
         rows = [{k: v[i] for k, v in outs.items()}
                 for i in range(len(windows))]
-        n_esc, esc_nj = self._track(pipe, task, fmt, windows, rows)
+        n_esc, esc_nj = self._track(pipe, task, fmt, windows, rows,
+                                    span=trk_id)
+        t_trk = tr.now() if tr is not None else 0.0
         self.ledger.record(task, fmt, n_real, n_padded, dt,
                            pipe.ops_per_window,
                            n_escalated=n_esc, escalation_extra_nj=esc_nj)
+        # after the trackers and the ledger: the results of one dispatch
+        # share this stamp
         done = time.perf_counter()
-        tr = self.tracer
-        if tr is not None:
-            # host-side stamps only: ready_wall/t0/done already exist for
-            # the ledger; tracing adds no clock reads on the jit path
-            tr.complete("dispatch", f"{task}/{fmt}", t0, done,
-                        track="dispatch",
-                        args={"task": task, "fmt": fmt, "B": B,
-                              "Bpad": Bpad})
-            for w in windows:
-                if w.ready_wall:
-                    tr.complete("stage", "ready->dispatch", w.ready_wall,
-                                t0, track=w.patient,
-                                args={"widx": w.widx, "task": task})
         for w, row in zip(windows, rows):
             self._append_result(WindowResult(
                 w.patient, task, w.widx, fmt, w.t0_s, row,
                 ready_wall=w.ready_wall, done_wall=done))
+        if tr is not None:
+            # the children tile the parent: stage, device, tracker, account
+            t_end = tr.now()
+            tr.complete("dispatch", "stage", t_in, t0, track="dispatch",
+                        parent=sid)
+            tr.complete("dispatch", "device", t0, t_dev, track="dispatch",
+                        parent=sid)
+            tr.complete("dispatch", "tracker", t_dev, t_trk,
+                        track="dispatch", sid=trk_id, parent=sid)
+            tr.complete("dispatch", "account", t_trk, t_end,
+                        track="dispatch", parent=sid)
+            tr.complete("dispatch", f"{task}/{fmt}", t_in, t_end,
+                        track="dispatch", sid=sid,
+                        args={"task": task, "fmt": fmt, "B": B,
+                              "Bpad": Bpad})
 
     def _append_result(self, r: WindowResult) -> None:
         """Retain one result, dropping the oldest past ``result_capacity``
@@ -418,8 +433,8 @@ class StreamEngine:
             ).inc(patient=v.patient))
 
     def _track(self, pipe: Pipeline, task: str, fmt: str,
-               windows: List[Window], rows: List[Dict[str, np.ndarray]]
-               ) -> Tuple[int, float]:
+               windows: List[Window], rows: List[Dict[str, np.ndarray]],
+               span: Optional[int] = None) -> Tuple[int, float]:
         """Run the per-patient stateful trackers over a dispatched batch.
 
         Windows hit each tracker in ``widx`` order (the pending groups are
@@ -427,7 +442,9 @@ class StreamEngine:
         outputs, and its quality signal feeds the router's escalation policy
         — affecting how the patient's NEXT windows are routed.  Windows that
         ran above the patient's static format are billed to the escalation
-        column, per patient and per group.
+        column, per patient and per group.  With a tracer, each window's
+        threshold round trip is a ``dispatch/tracker.threshold`` span, a
+        child of ``span``.
         """
         if pipe.make_tracker is None:
             return 0, 0.0
@@ -436,12 +453,18 @@ class StreamEngine:
         # energy delta are memoized so the per-window loop stays cheap
         base_fmts: Dict[str, str] = {}
         extra_by_base: Dict[str, float] = {}
+        tracer = self.tracer
         for w, row in zip(windows, rows):
             key = (w.patient, task)
             tr = self._trackers.get(key)
             if tr is None:
-                tr = self._trackers[key] = pipe.make_tracker(w.patient)
+                tr = self._trackers[key] = pipe.make_tracker(
+                    w.patient, clock=None if tracer is None else tracer.now)
             upd = tr.update(w.widx, row, fmt)
+            if tracer is not None and upd.threshold_s is not None:
+                tracer.complete("dispatch", "tracker.threshold",
+                                *upd.threshold_s, track="dispatch",
+                                parent=span, key=f"{w.patient}/{w.widx}")
             row["peaks"] = upd.new_peaks
             base_fmt = base_fmts.get(w.patient)
             if base_fmt is None:

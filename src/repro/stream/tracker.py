@@ -19,7 +19,7 @@ middle of a beat decision).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -39,6 +39,9 @@ class TrackerUpdate:
     thr: float                # adaptive threshold after this window
     boundary_gap: float       # min |candidate max − thr|; inf if no maxima
     mid_refractory: bool      # accepted beat's refractory spans the frontier
+    # (start, end) of the window's threshold round trip on the tracker's
+    # clock; None without a clock
+    threshold_s: Optional[Tuple[float, float]] = None
 
 
 class RPeakTracker:
@@ -47,16 +50,18 @@ class RPeakTracker:
     ``update`` must see windows in ``widx`` order exactly once — which is
     precisely the dispatcher's emission guarantee — and each window's score
     vector must be one hop long, so absolute sample positions fall out of
-    the fold's running sample count.
+    the fold's running sample count.  With a ``clock``, each update times
+    the fold's threshold round trip (``TrackerUpdate.threshold_s``).
     """
 
     def __init__(self, patient: str = "", fs: int = ECG_FS,
                  window_samples: Optional[int] = None,
-                 window_s: float = RPEAK_WINDOW_S):
+                 window_s: float = RPEAK_WINDOW_S,
+                 clock: Optional[Callable[[], float]] = None):
         self.patient = patient
         self.window_samples = (int(window_samples) if window_samples
                                else int(round(window_s * fs)))
-        self.fold = RPeakFold(fs=fs)
+        self.fold = RPeakFold(fs=fs, clock=clock)
         self.next_widx = 0
         self.peaks: List[int] = []      # every confirmed peak so far
         self.windows_by_fmt: Dict[str, int] = {}
@@ -88,7 +93,8 @@ class RPeakTracker:
         self.peaks.extend(int(p) for p in new)
         return TrackerUpdate(
             self.patient, widx, fmt, new, self.fold.thr,
-            self._boundary_gap(scores), self._mid_refractory())
+            self._boundary_gap(scores), self._mid_refractory(),
+            self.fold.threshold_s)
 
     def finalize(self, fmt: str) -> np.ndarray:
         """End of stream: flush the fold's deferred lookahead margin."""

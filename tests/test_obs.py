@@ -68,27 +68,61 @@ def test_tracer_ring_drops_oldest_and_counts():
 def test_tracer_chrome_export_is_valid_and_tracked(tmp_path):
     tr = Tracer()
     t0 = tr.now()
+    sid = tr.new_id()
+    tr.complete("dispatch", "tracker.threshold", t0, t0 + 1e-3,
+                track="p-0", parent=sid, key="p-0/3")
+    tr.instant("serve", "retire", track="p-0", args={"rid": 3}, key=3)
     tr.complete("dispatch", "cough/posit16", t0, t0 + 2e-3,
-                track="dispatch", args={"B": 4})
-    tr.complete("stage", "ready->dispatch", t0, t0 + 1e-3, track="p-0")
-    tr.instant("session", "deliver", track="p-0", args={"seq": 3})
+                track="dispatch", args={"B": 4}, sid=sid)
     path = tmp_path / "trace.json"
     tr.export(str(path))
     doc = json.loads(path.read_text())
     events = validate_chrome_trace(doc)
     assert len(events) == 3
-    assert {e["cat"] for e in events} == {"dispatch", "stage", "session"}
+    assert {e["cat"] for e in events} == {"dispatch", "serve"}
     # spans on the same track share a tid; the metadata names it
     by_name = {e["name"]: e for e in events}
-    assert by_name["ready->dispatch"]["tid"] == by_name["deliver"]["tid"]
+    assert by_name["tracker.threshold"]["tid"] == by_name["retire"]["tid"]
     meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
     assert {m["args"]["name"] for m in meta} == {"dispatch", "p-0"}
     # the complete span's duration is the recorded wall, in µs
     assert by_name["cough/posit16"]["dur"] == pytest.approx(2e3, rel=1e-6)
+    # id, parent and key ride in args, beside the caller's own
+    assert by_name["cough/posit16"]["args"] == {"B": 4, "id": sid}
+    assert by_name["tracker.threshold"]["args"]["parent"] == sid
+    assert by_name["tracker.threshold"]["args"]["key"] == "p-0/3"
+    assert by_name["retire"]["args"]["rid"] == 3
     with pytest.raises(ValueError):
         validate_chrome_trace({"traceEvents": [{"ph": "X", "pid": 0}]})
     with pytest.raises(ValueError):
         validate_chrome_trace({})
+
+
+def test_tracer_ids_parents_and_self_time():
+    tr = Tracer()
+    t0 = tr.now()
+    parent = tr.new_id()          # taken before its children close
+    dev = tr.complete("dispatch", "device", t0, t0 + 1e-3, parent=parent)
+    trk = tr.complete("dispatch", "tracker", t0 + 1e-3, t0 + 3e-3,
+                      parent=parent, key="p-0/4")
+    assert tr.complete("dispatch", "rpeak/posit10", t0, t0 + 4e-3,
+                       args={"B": 2}, sid=parent) == parent
+    inst = tr.instant("serve", "retire", parent=parent, key=7)
+    assert len({parent, dev, trk, inst}) == 4
+    evs = tr.events()
+    # fields 0-6 keep their places; id, parent and key follow
+    assert evs[1] == ("X", "dispatch", "tracker", t0 + 1e-3, t0 + 3e-3,
+                      "main", None, trk, parent, "p-0/4")
+    ph, cat, name, start, end = evs[2][:5]
+    assert (ph, cat, name, start, end) == ("X", "dispatch", "rpeak/posit10",
+                                           t0, t0 + 4e-3)
+    assert evs[2][5:] == ("main", {"B": 2}, parent, None, None)
+    assert evs[3][0] == "i" and evs[3][7:] == (inst, parent, 7)
+    # self time: the duration less what the children cover
+    st = tr.self_times()
+    assert st[parent] == pytest.approx(1e-3)
+    assert st[dev] == pytest.approx(1e-3) and st[trk] == pytest.approx(2e-3)
+    assert inst not in st
 
 
 # ---------------------------------------------------------------------------
@@ -549,9 +583,46 @@ def test_fleet_64_patient_tcp_traced_bit_identical_to_untraced(pipelines):
     assert tt["ecg-031"]["evictions"] == 1
     assert tt["fleet"]["dup_frames"] > 0      # faults actually injected
     assert tt["fleet"]["reordered_frames"] > 0
-    # 5. the trace: valid Chrome JSON covering ≥5 span categories
+    # 5. the trace: valid Chrome JSON covering ≥4 span categories
     events = validate_chrome_trace(tracer.chrome_trace())
     cats = {e["cat"] for e in events}
-    assert len(cats) >= 5, cats
-    assert {"frame", "session", "stage", "dispatch", "drain"} <= cats
+    assert len(cats) >= 4, cats
+    assert {"frame", "dispatch", "drain"} <= cats
     assert "reorder" in cats                  # deferred frames were held
+
+
+def test_traced_fleet_spans_nest_inside_each_dispatch(pipelines):
+    """Each dispatch's children (stage, device, tracker, account) lie
+    inside it and cover it; every dispatched window has one threshold
+    round trip under its dispatch's tracker span; with ACKs on, every read
+    that delivered frames is followed by one ``flush_acks``."""
+    tracer = Tracer()
+    eng = StreamEngine({"rpeak": pipelines["rpeak"]}, max_batch=16,
+                       pad_policy="max", result_capacity=None,
+                       tracer=tracer)
+    sim = FleetSimulator(n_patients=12, windows=2, seed=3, n_cough=0)
+    sup, _, _ = _run_tcp_fleet(eng, sim)
+    results = sup.pop()
+    assert len(results) == 24
+    spans = [ev for ev in tracer.events() if ev[0] == "X"]
+    by_id = {ev[7]: ev for ev in spans}
+    dispatches = [ev for ev in spans if ev[1] == "dispatch" and ev[8] is None]
+    assert dispatches
+    self_time = tracer.self_times()
+    for par in dispatches:
+        assert par[2].startswith("rpeak/")
+        kids = [ev for ev in spans if ev[8] == par[7]]
+        assert [k[2] for k in kids] == ["stage", "device", "tracker",
+                                        "account"]
+        assert all(par[3] <= k[3] <= k[4] <= par[4] for k in kids)
+        assert self_time[par[7]] <= 0.05 * (par[4] - par[3])
+    thr = [ev for ev in spans if ev[2] == "tracker.threshold"]
+    assert sorted(ev[9] for ev in thr) == sorted(
+        f"{r.patient}/{r.widx}" for r in results)
+    for ev in thr:
+        trk = by_id[ev[8]]
+        assert trk[2] == "tracker" and trk[3] <= ev[3] <= ev[4] <= trk[4]
+    reads = [ev for ev in spans if ev[1:3] == ("frame", "decode")]
+    acks = [ev for ev in spans if ev[1:3] == ("frame", "flush_acks")]
+    assert reads and len(acks) == len(reads)
+    assert sum(ev[6]["acks"] for ev in acks) > 0

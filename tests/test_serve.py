@@ -14,6 +14,7 @@ import pytest
 from repro.configs import CONFIGS, reduced
 from repro.launch.mesh import make_debug_mesh_info
 from repro.models import build_model
+from repro.obs import Tracer
 from repro.serve import (AGGRESSIVE_SERVE, Completion, Request, ServeConfig,
                          ServePolicy, ServingEngine, Scheduler)
 from repro.serve.accounting import (kv_traffic_bytes, prefill_energy_nj,
@@ -226,6 +227,43 @@ def test_engine_keeps_the_logits_it_sampled_from(served_model):
         assert rows.shape == (len(comps[rid].tokens), cfg.vocab)
         assert np.isfinite(rows).all()
         np.testing.assert_array_equal(rows.argmax(-1), comps[rid].tokens)
+
+
+def test_traced_step_holds_each_decode_and_its_accounting(served_model):
+    """Every ``serve/decode`` has one ``serve/account`` sibling right after
+    it, both inside one ``serve/step``; admissions are children of their
+    step, each prefill a child of its admission, keyed by the rid."""
+    cfg, minfo, model, params = served_model
+    tracer = Tracer()
+    with minfo.mesh:
+        eng = ServingEngine(model, params,
+                            ServeConfig(batch_size=2, max_prompt=16,
+                                        max_new_tokens=4, seed=3),
+                            AGGRESSIVE_SERVE, tracer=tracer)
+        prompts = _prompts(cfg, [5, 3, 9, 4, 7], seed=1)
+        rids = [eng.submit(p) for p in prompts[:4]]
+        rids.append(eng.submit(
+            prompts[4], max_new_tokens=2,
+            policy=ServePolicy(weights="posit16", kv="posit16")))
+        eng.run()
+    spans = [ev for ev in tracer.events() if ev[0] == "X"]
+    steps = {ev[7]: ev for ev in spans if ev[2] == "step"}
+    decodes = [ev for ev in spans if ev[2] == "decode"]
+    accounts = [ev for ev in spans if ev[2] == "account"]
+    assert decodes and len(accounts) == len(decodes)
+    for d in decodes:
+        step = steps[d[8]]
+        sib = [a for a in accounts if a[8] == d[8] and a[5] == d[5]]
+        assert len(sib) == 1
+        assert step[3] <= d[3] <= d[4] <= sib[0][3] <= sib[0][4] <= step[4]
+        assert step[6]["rows"][d[5].removeprefix("lane:")] == d[6]["rows"]
+    admits = {ev[7]: ev for ev in spans if ev[2] == "admit"}
+    assert sorted(ev[9] for ev in admits.values()) == sorted(rids)
+    assert sum(s[6]["admitted"] for s in steps.values()) == len(rids)
+    for a in admits.values():
+        assert steps[a[8]][3] <= a[3] <= a[4] <= steps[a[8]][4]
+    for p in (ev for ev in spans if ev[2] == "prefill"):
+        assert admits[p[8]][9] == p[9] == p[6]["rid"]
 
 
 def test_engine_eos_frees_slot(served_model):
