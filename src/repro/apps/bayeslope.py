@@ -10,15 +10,15 @@ Pipeline per the paper's description of [8]:
 
 Stages 1-2 run vectorized in the target format over fixed windows
 (``rpeak_window_scores``) — the same jit-compiled core the streaming runtime
-dispatches. Stages 3-4 are *window-incremental*: ``threshold_update`` (an
+dispatches. Stages 3-4 are *window-incremental*: ``fold_thresholds`` (an
 incremental 2-means over a bounded score reservoir, arithmetic in the
-window's format), ``stitch_peaks`` (greedy-refractory candidate selection
-stitched across window boundaries via a deferred commit frontier) and
-``recover_gaps`` (the Bayesian RR-prior gap walk over the retained score
-tail). ``RPeakFold`` threads the cross-window state through those functions;
+window's format, many windows in one launch), ``stitch_peaks``
+(greedy-refractory candidate selection stitched across window boundaries
+via a deferred commit frontier) and ``recover_gaps`` (the Bayesian
+RR-prior gap walk over the retained score tail). ``RPeakFold`` threads the cross-window state through those functions;
 ``detect_rpeaks`` is a thin fold over the windows of a full recording, and
 the streaming ``repro.stream.tracker.RPeakTracker`` drives the *same* fold
-one window at a time — so streaming peak output is identical to the offline
+as windows arrive — so streaming peak output is identical to the offline
 path by construction, and ``tests/test_stream_parity.py`` locks it down.
 
 The stage 3-4 control flow runs in float64 on the format-rounded scores (on
@@ -28,17 +28,21 @@ threshold itself runs in the window's routed arithmetic.
 """
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
-from typing import Callable, Dict, List, Optional, Tuple
+import time
+from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.arith import Arith, fusion_cache_key
+from repro.core.arith import (Arith, backend_overrides, fusion_cache_key,
+                              get_round_backend)
 from repro.data.biosignals import ECG_FS, ecg_dataset
 
-from .kmeans import kmeans_1d
+from .kmeans import kmeans_1d_rows
 from .metrics import rpeak_f1
 
 # Canonical fold/stream window (the streaming runtime's R-peak hop grid).
@@ -55,6 +59,10 @@ RESERVOIR_SIZE = 500
 # the 2 s / 500-sample window the reservoir spans the last ~5 windows (10 s)
 # of scores — the threshold adapts on that horizon.
 RESERVOIR_STRIDE = 5
+# Rows of one launch of the batched 2-means threshold, one window each: a
+# fixed shape, so each format compiles one threshold program whatever the
+# dispatch's size or the reservoirs' lengths.
+THRESHOLD_ROWS = 64
 # Candidates collected before the RR estimate bootstraps (median of diffs).
 RR_BOOT = 8
 # Retained score-tail cap: the Bayesian gap walk can re-search at most this
@@ -118,18 +126,52 @@ def _score_fn(fmt_name: str, n: int):
     return _score_fn_cached(fmt_name, n, fusion_cache_key())
 
 
-@functools.lru_cache(maxsize=None)
-def _kmeans_fn_cached(fmt_name: str, n: int, warm: bool,
-                      backend_key: tuple):
+def _threshold_program(fmt_name: str, args: Sequence[jax.ShapeDtypeStruct]):
+    """Compile the batched 2-means of one format for ``args``, the shapes
+    of its inputs: reservoirs (rows, entries), valid lengths, warm starts
+    (rows, 2) and whether each row has one, predecessor rows (-1: none),
+    and the chain depth to loop over.
+
+    Its posit rounds are plain jnp ops, bit-identical to the Pallas round
+    kernel: XLA fuses each k-means iteration of the chain loop into a few
+    kernels, where a Pallas round is a custom call it cannot fuse."""
     ar = Arith.make(fmt_name)
-    if warm:
-        return jax.jit(lambda x, init: kmeans_1d(ar, x, k=2, init=init))
-    return jax.jit(lambda x: kmeans_1d(ar, x, k=2))
+
+    def run(x, n, init, has_init, pred, depth):
+        valid = jnp.arange(x.shape[1])[None, :] < n[:, None]
+        chained = pred >= 0
+        src = jnp.maximum(pred, 0)
+
+        def level(_, cents):
+            # a row at chain depth d is final after level d: its
+            # predecessor was final after level d - 1.  Non-finite
+            # centroids never warm-start; that row starts cold.
+            prev = cents[src]
+            start = jnp.where(chained[:, None], prev, init)
+            warm = jnp.where(chained, jnp.all(jnp.isfinite(prev), axis=1),
+                             has_init)
+            return kmeans_1d_rows(ar, x, valid, start, warm)
+
+        return jax.lax.fori_loop(0, depth, level, jnp.zeros_like(init))
+
+    rb = "jnp" if get_round_backend() == "pallas" else None
+    with backend_overrides(round_backend=rb):
+        return jax.jit(run).lower(*args).compile()
 
 
-def _kmeans_fn(fmt_name: str, n: int, warm: bool):
-    """jit-compiled 2-means for one (format, reservoir length, warm-start)."""
-    return _kmeans_fn_cached(fmt_name, n, warm, fusion_cache_key())
+@functools.lru_cache(maxsize=None)
+def _threshold_fn_cached(fmt_name: str, backend_key: tuple):
+    R, N = THRESHOLD_ROWS, RESERVOIR_SIZE
+    S = jax.ShapeDtypeStruct
+    return _threshold_program(fmt_name, (
+        S((R, N), jnp.float32), S((R,), jnp.int32), S((R, 2), jnp.float32),
+        S((R,), jnp.bool_), S((R,), jnp.int32), S((), jnp.int32)))
+
+
+def _threshold_fn(fmt_name: str):
+    """The batched 2-means of one format: (THRESHOLD_ROWS, RESERVOIR_SIZE)
+    reservoirs, whatever their lengths, in one compiled program."""
+    return _threshold_fn_cached(fmt_name, fusion_cache_key())
 
 
 # ---------------------------------------------------------------------------
@@ -148,25 +190,78 @@ def reservoir_update(reservoir: np.ndarray, scores: np.ndarray,
     return np.concatenate([reservoir, sub])[-size:]
 
 
-def threshold_update(ar: Arith, reservoir: np.ndarray,
-                     init: Optional[np.ndarray] = None
-                     ) -> Tuple[float, np.ndarray]:
-    """Incremental 2-means threshold over the reservoir, in ``ar``'s format.
+@dataclasses.dataclass
+class StagedWindow:
+    """One window of a fold between its reservoir step (``RPeakFold.stage``)
+    and the rest of its step (``RPeakFold.advance``)."""
 
-    ``init`` warm-starts the centroids from the previous window's solution.
-    Returns (thr, centroids): thr = 0.3·low + 0.7·high (weighted toward the
-    R cluster), NaN when the arithmetic collapsed (e.g. FP8E4M3 → NaN).
+    scores: np.ndarray                # sanitized, float64
+    # the reservoir its threshold clusters; None: an empty window, which
+    # keeps the fold's threshold
+    reservoir: Optional[np.ndarray]
+    # set by ``fold_thresholds``: the 2-means centroids and threshold
+    cents: Optional[np.ndarray] = None
+    thr: Optional[float] = None
+
+
+def _chain_depth(pred: np.ndarray) -> int:
+    """Windows in the deepest chain of a launch: the levels it loops over."""
+    depth = np.zeros(len(pred), np.int64)
+    for i, p in enumerate(pred):
+        if p >= 0:
+            depth[i] = depth[p] + 1
+    return int(depth.max(initial=-1)) + 1
+
+
+def fold_thresholds(ar: Arith,
+                    rows: Sequence[Tuple["RPeakFold", StagedWindow]],
+                    on_launch: Optional[Callable[[float, float, int, int],
+                                                 None]] = None) -> None:
+    """Incremental 2-means thresholds of staged windows, in ``ar``'s format.
+
+    ``rows`` holds each fold's staged windows in its window order.  One
+    launch of the batched 2-means takes ``THRESHOLD_ROWS`` of them, with
+    one copy of the centroids to the host.  A window warm-starts from the
+    previous window of its fold: from a row before it in the same launch
+    (the launch loops over the chains' depth), else from the centroids the
+    fold holds.  Sets each window's ``thr`` = 0.3·low + 0.7·high (weighted
+    toward the R cluster), NaN when the arithmetic collapsed (e.g. FP8E4M3
+    → NaN), and each fold's warm start: its last centroids where finite,
+    else None, so its next window starts cold.
+
+    ``on_launch(start, end, rows, depth)`` hears of each launch, timed on
+    ``time.perf_counter``.
     """
-    x = jnp.asarray(reservoir, jnp.float32)
-    if init is None:
-        cents = _kmeans_fn(ar.name, len(reservoir), False)(x)
-    else:
-        cents = _kmeans_fn(ar.name, len(reservoir), True)(
-            x, jnp.asarray(init, jnp.float32))
-    cents = np.asarray(cents, np.float32)
-    c = np.sort(np.asarray(cents, np.float64))
-    thr = 0.3 * c[0] + 0.7 * c[1]
-    return (float(thr) if np.isfinite(thr) else float("nan")), cents
+    fn = _threshold_fn(ar.name)
+    for c0 in range(0, len(rows), THRESHOLD_ROWS):
+        chunk = rows[c0: c0 + THRESHOLD_ROWS]
+        x = np.zeros((THRESHOLD_ROWS, RESERVOIR_SIZE), np.float32)
+        n = np.zeros(THRESHOLD_ROWS, np.int32)
+        init = np.zeros((THRESHOLD_ROWS, 2), np.float32)
+        has_init = np.zeros(THRESHOLD_ROWS, bool)
+        pred = np.full(THRESHOLD_ROWS, -1, np.int32)
+        last: Dict[int, int] = {}
+        for i, (fold, st) in enumerate(chunk):
+            res = st.reservoir
+            x[i, :len(res)] = res
+            n[i] = len(res)
+            j = last.get(id(fold))
+            if j is not None:
+                pred[i] = j
+            elif fold.cents is not None:
+                init[i], has_init[i] = fold.cents, True
+            last[id(fold)] = i
+        depth = _chain_depth(pred[:len(chunk)])
+        t0 = time.perf_counter()
+        cents = np.asarray(fn(x, n, init, has_init, pred, np.int32(depth)))
+        if on_launch is not None:
+            on_launch(t0, time.perf_counter(), len(chunk), depth)
+        for (fold, st), c in zip(chunk, cents):
+            lo, hi = np.sort(c.astype(np.float64))
+            thr = 0.3 * lo + 0.7 * hi
+            st.cents = c
+            st.thr = float(thr) if np.isfinite(thr) else float("nan")
+            fold.cents = c if np.all(np.isfinite(c)) else None
 
 
 def stitch_peaks(e: np.ndarray, start: int, committed: int, commit_to: int,
@@ -242,13 +337,13 @@ def recover_gaps(e: np.ndarray, start: int, out: List[int], nxt: int,
 class RPeakFold:
     """Cross-window BayeSlope stages 3-4 state machine.
 
-    One instance per ECG stream; ``push`` consumes consecutive windows'
-    stage 1-2 scores and returns newly *confirmed* peaks (absolute sample
-    indices, ascending across calls).  The offline ``detect_rpeaks`` and the
-    streaming ``RPeakTracker`` both drive this class with the identical call
-    sequence — every push with ``final=False``, then one empty ``finalize``
-    flush — which is what makes streaming output equal offline output for
-    any chunking of the input.
+    One instance per ECG stream; it consumes consecutive windows' stage 1-2
+    scores and returns newly *confirmed* peaks (absolute sample indices,
+    ascending across calls).  The offline ``detect_rpeaks`` and the
+    streaming ``RPeakTracker`` both drive this class with the identical
+    sequence of window steps, thresholds from the same compiled program —
+    then one empty ``finalize`` flush — which is what makes streaming
+    output equal offline output for any chunking of the input.
 
     State carried across windows:
       * score ``reservoir`` + warm-started centroids → adaptive threshold,
@@ -261,23 +356,24 @@ class RPeakFold:
       * the RR estimate (bootstrapped from the first ``rr_boot`` candidates,
         then EMA-updated exactly as the paper's stage 4).
 
-    ``clock`` (default None) times each push's threshold round trip into
-    ``threshold_s``; without one the fold reads no clock.
+    Each window takes two steps: ``stage`` feeds its scores to the
+    reservoir, and ``advance`` — once ``fold_thresholds`` has set the
+    window's threshold — does the rest of it.  Reservoirs depend on scores
+    alone, so a batch stages all its windows and clusters them in one
+    launch before any advances; ``push`` takes both steps for one window.
     """
 
     def __init__(self, fs: int = ECG_FS,
-                 reservoir_size: int = RESERVOIR_SIZE,
                  reservoir_stride: int = RESERVOIR_STRIDE,
-                 rr_boot: int = RR_BOOT, tail_max_s: float = TAIL_MAX_S,
-                 clock: Optional[Callable[[], float]] = None):
+                 rr_boot: int = RR_BOOT, tail_max_s: float = TAIL_MAX_S):
         self.fs = fs
         self.refractory = int(REFRACTORY_S * fs)
-        self.reservoir_size = reservoir_size
         self.reservoir_stride = reservoir_stride
         self.rr_boot = rr_boot
         self.tail_max = int(tail_max_s * fs)
         self.reservoir = np.zeros(0, np.float32)
         self.cents: Optional[np.ndarray] = None   # warm-start centroids
+        self.staged: Deque[StagedWindow] = collections.deque()
         self.thr = float("nan")
         self.tail = np.zeros(0, np.float64)
         self.tail_start = 0
@@ -289,33 +385,45 @@ class RPeakFold:
         self.rr: Optional[float] = None
         self.emitted = 0
         self.finalized = False
-        self.clock = clock
-        self.threshold_s: Optional[Tuple[float, float]] = None
 
-    def push(self, ar: Arith, scores: np.ndarray,
-             final: bool = False) -> np.ndarray:
-        """Consume the next window's scores; return newly confirmed peaks."""
+    def stage(self, scores: np.ndarray) -> StagedWindow:
+        """First step of the next window: its scores into the reservoir.
+
+        The SANITIZED scores enter the reservoir: one NaN/Inf artifact
+        window must not poison the threshold for the reservoir's whole FIFO
+        lifetime after the arithmetic recovers."""
         if self.finalized:
             raise RuntimeError("RPeakFold already finalized")
         s32 = np.asarray(scores, np.float32).reshape(-1)
         s = np.nan_to_num(np.asarray(s32, np.float64),
                           nan=0.0, posinf=0.0, neginf=0.0)
-        if len(s32):
-            # threshold from the bounded reservoir, in this window's format.
-            # The SANITIZED scores enter the reservoir: one NaN/Inf artifact
-            # window must not poison the threshold for the reservoir's whole
-            # FIFO lifetime after the arithmetic recovers.  NaN centroids
-            # (collapsed arithmetic) never warm-start the next k-means.
-            self.reservoir = reservoir_update(
-                self.reservoir, s, self.reservoir_size,
-                self.reservoir_stride)
-            clock = self.clock
-            t_thr = clock() if clock is not None else 0.0
-            self.thr, cents = threshold_update(ar, self.reservoir,
-                                               init=self.cents)
-            if clock is not None:
-                self.threshold_s = (t_thr, clock())
-            self.cents = cents if np.all(np.isfinite(cents)) else None
+        reservoir = None
+        if len(s):
+            self.reservoir = reservoir = reservoir_update(
+                self.reservoir, s, RESERVOIR_SIZE, self.reservoir_stride)
+        st = StagedWindow(s, reservoir)
+        self.staged.append(st)
+        return st
+
+    def push(self, ar: Arith, scores: np.ndarray,
+             final: bool = False) -> np.ndarray:
+        """Consume the next window's scores; return newly confirmed peaks.
+        The window's threshold is a launch of one row."""
+        st = self.stage(scores)
+        if st.reservoir is not None:
+            fold_thresholds(ar, [(self, st)])
+        return self.advance(final)
+
+    def advance(self, final: bool = False) -> np.ndarray:
+        """Second step of the oldest staged window, its threshold set:
+        stitching, the RR prior and the gap walk; returns newly confirmed
+        peaks."""
+        st = self.staged.popleft()
+        if st.reservoir is not None:
+            if st.thr is None:
+                raise RuntimeError("window advanced before its threshold")
+            self.thr = st.thr
+        s = st.scores
         self.tail = np.concatenate([self.tail, s])
         self.end += len(s)
         commit_to = self.end if final else max(

@@ -32,7 +32,8 @@ dispatch/<task>/<fmt>       one engine dispatch, staging to appended results
   dispatch/stage            the padded numpy batch is built
   dispatch/device           jit call → outputs are numpy arrays on the host
   dispatch/tracker          the per-patient trackers over the batch
-    dispatch/tracker.threshold  one window's 2-means round trip (key)
+    dispatch/tracker.threshold  one batched 2-means launch, one host copy
+                            (``args``: rows, depth of its deepest chain)
   dispatch/account          ledger record, results appended
 drain/supervisor.poll       results popped by the supervisor
 serve/step                  one ``ServingEngine.step`` (admitted, rows)

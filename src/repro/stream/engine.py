@@ -36,6 +36,7 @@ from .accounting import EnergyLedger, window_energy_nj
 from .pipelines import Pipeline
 from .ring import Window, WindowDispatcher
 from .router import PrecisionRouter
+from .tracker import stage_thresholds
 
 
 def bucket_size(n: int, max_batch: int) -> int:
@@ -131,8 +132,8 @@ class StreamEngine:
         ``repro.obs.NULL_METRICS`` disables the plane at ~zero cost).  The
         session/supervisor/server layers share it.  ``tracer`` (a
         ``repro.obs.Tracer``, default off) records each dispatch split
-        into stage, device, tracker (with each window's threshold round
-        trip) and account — both are host-side only and never enter jit.
+        into stage, device, tracker (with each batched threshold launch)
+        and account — both are host-side only and never enter jit.
 
         ``mesh_info`` (a ``repro.distributed.MeshInfo``, e.g. from
         ``launch.mesh.make_fleet_mesh_info``) shards every dispatch over the
@@ -175,6 +176,12 @@ class StreamEngine:
             "fusion_cache_key() flips observed between dispatches — "
             "each flip retraces every live (task, fmt, shape) program")
         self._last_fusion_key = None
+        self._thr_launches = self.metrics.counter(
+            "tracker_threshold_launches_total",
+            "batched 2-means threshold launches of the trackers")
+        self._thr_rows = self.metrics.counter(
+            "tracker_threshold_rows_total",
+            "windows whose 2-means threshold those launches computed")
         self.results: Deque[WindowResult] = collections.deque()
         self._evicted: Set[Tuple[str, str]] = set()
         self._dispatchers: Dict[Tuple[str, str], WindowDispatcher] = {}
@@ -437,14 +444,16 @@ class StreamEngine:
                span: Optional[int] = None) -> Tuple[int, float]:
         """Run the per-patient stateful trackers over a dispatched batch.
 
-        Windows hit each tracker in ``widx`` order (the pending groups are
-        FIFO per patient), the tracker's confirmed peaks land on the window's
+        First every window's 2-means threshold, the whole batch in one
+        launch (``tracker.stage_thresholds``); then windows hit each
+        tracker in ``widx`` order (the pending groups are FIFO per
+        patient), the tracker's confirmed peaks land on the window's
         outputs, and its quality signal feeds the router's escalation policy
         — affecting how the patient's NEXT windows are routed.  Windows that
         ran above the patient's static format are billed to the escalation
-        column, per patient and per group.  With a tracer, each window's
-        threshold round trip is a ``dispatch/tracker.threshold`` span, a
-        child of ``span``.
+        column, per patient and per group.  With a tracer, each threshold
+        launch is a ``dispatch/tracker.threshold`` span, a child of
+        ``span``, with its rows and deepest chain in ``args``.
         """
         if pipe.make_tracker is None:
             return 0, 0.0
@@ -454,17 +463,26 @@ class StreamEngine:
         base_fmts: Dict[str, str] = {}
         extra_by_base: Dict[str, float] = {}
         tracer = self.tracer
-        for w, row in zip(windows, rows):
+        trackers = []
+        for w in windows:
             key = (w.patient, task)
             tr = self._trackers.get(key)
             if tr is None:
-                tr = self._trackers[key] = pipe.make_tracker(
-                    w.patient, clock=None if tracer is None else tracer.now)
+                tr = self._trackers[key] = pipe.make_tracker(w.patient)
+            trackers.append(tr)
+
+        def launched(t0: float, t1: float, n_rows: int, depth: int) -> None:
+            self._thr_launches.inc()
+            self._thr_rows.inc(n_rows)
+            if tracer is not None:
+                tracer.complete("dispatch", "tracker.threshold", t0, t1,
+                                track="dispatch", parent=span,
+                                args={"rows": n_rows, "depth": depth})
+
+        stage_thresholds([(tr, w.widx, row) for tr, w, row
+                          in zip(trackers, windows, rows)], fmt, launched)
+        for tr, w, row in zip(trackers, windows, rows):
             upd = tr.update(w.widx, row, fmt)
-            if tracer is not None and upd.threshold_s is not None:
-                tracer.complete("dispatch", "tracker.threshold",
-                                *upd.threshold_s, track="dispatch",
-                                parent=span, key=f"{w.patient}/{w.widx}")
             row["peaks"] = upd.new_peaks
             base_fmt = base_fmts.get(w.patient)
             if base_fmt is None:

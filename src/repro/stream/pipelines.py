@@ -60,12 +60,12 @@ class Pipeline:
     batched outputs; rows are independent, so any batch size reuses the same
     compiled code per bucket and padding rows never affect real rows.
 
-    ``make_tracker`` (optional) builds a per-patient stateful tracker from a
-    patient id and a ``clock`` (``None`` unless the engine traces; with one,
-    each update's ``threshold_s`` times its threshold round trip); the
-    engine feeds it each window's outputs in ``widx`` order
-    (``tracker.update(widx, outputs, fmt)``) and its updates land on the
-    ``WindowResult`` plus the router's escalation feedback.
+    ``make_tracker`` (optional) builds a per-patient ``RPeakTracker`` from a
+    patient id; the engine stages each batch's thresholds in one launch
+    (``tracker.stage_thresholds``), then feeds each tracker its windows'
+    outputs in ``widx`` order (``tracker.update(widx, outputs, fmt)``), and
+    the updates land on the ``WindowResult`` plus the router's escalation
+    feedback.
     """
 
     name: str
@@ -158,8 +158,8 @@ def rpeak_pipeline(window_s: float = RPEAK_WINDOW_S,
         return _rpeak_batch_fn(fmt, peak_threshold, refr)
 
     make_tracker = (
-        (lambda patient, clock=None: RPeakTracker(
-            patient, fs=ECG_FS, window_samples=n, clock=clock))
+        (lambda patient: RPeakTracker(
+            patient, fs=ECG_FS, window_samples=n))
         if track_peaks else None)
     return Pipeline("rpeak", spec, make_fn, rpeak_window_op_counts(n),
                     make_tracker)

@@ -9,6 +9,12 @@ through a deferred commit frontier, and the Bayesian RR-prior gap walk over
 the retained score tail.  Streaming peaks therefore equal offline peaks for
 any chunking of the same record (``tests/test_stream_parity.py``).
 
+A dispatched batch takes two phases: ``stage_thresholds`` feeds every
+window's scores to its tracker's reservoir and computes all the batch's
+2-means thresholds in one launch, chains of one patient's consecutive
+windows included; then ``RPeakTracker.update`` runs the rest of each window
+in ``widx`` order.
+
 Each update also produces the quality-feedback signal the
 ``PrecisionRouter`` escalation policy consumes: how close the window's
 candidate maxima came to the decision threshold (``boundary_gap``), and
@@ -19,11 +25,11 @@ middle of a beat decision).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.apps.bayeslope import RPEAK_WINDOW_S, RPeakFold
+from repro.apps.bayeslope import RPEAK_WINDOW_S, RPeakFold, fold_thresholds
 from repro.core.arith import Arith
 from repro.data.biosignals import ECG_FS
 
@@ -39,9 +45,6 @@ class TrackerUpdate:
     thr: float                # adaptive threshold after this window
     boundary_gap: float       # min |candidate max − thr|; inf if no maxima
     mid_refractory: bool      # accepted beat's refractory spans the frontier
-    # (start, end) of the window's threshold round trip on the tracker's
-    # clock; None without a clock
-    threshold_s: Optional[Tuple[float, float]] = None
 
 
 class RPeakTracker:
@@ -50,19 +53,19 @@ class RPeakTracker:
     ``update`` must see windows in ``widx`` order exactly once — which is
     precisely the dispatcher's emission guarantee — and each window's score
     vector must be one hop long, so absolute sample positions fall out of
-    the fold's running sample count.  With a ``clock``, each update times
-    the fold's threshold round trip (``TrackerUpdate.threshold_s``).
+    the fold's running sample count.  A window that ``stage_thresholds``
+    did not stage has its threshold computed alone.
     """
 
     def __init__(self, patient: str = "", fs: int = ECG_FS,
                  window_samples: Optional[int] = None,
-                 window_s: float = RPEAK_WINDOW_S,
-                 clock: Optional[Callable[[], float]] = None):
+                 window_s: float = RPEAK_WINDOW_S):
         self.patient = patient
         self.window_samples = (int(window_samples) if window_samples
                                else int(round(window_s * fs)))
-        self.fold = RPeakFold(fs=fs, clock=clock)
+        self.fold = RPeakFold(fs=fs)
         self.next_widx = 0
+        self.next_staged = 0            # windows staged so far
         self.peaks: List[int] = []      # every confirmed peak so far
         self.windows_by_fmt: Dict[str, int] = {}
         self._ars: Dict[str, Arith] = {}
@@ -74,27 +77,40 @@ class RPeakTracker:
             ar = self._ars[fmt] = Arith.make(fmt)
         return ar
 
-    def update(self, widx: int, outputs: Dict[str, np.ndarray],
-               fmt: str) -> TrackerUpdate:
-        """Feed window ``widx``'s pipeline outputs (needs ``scores``)."""
-        if widx != self.next_widx:
+    def stage(self, widx: int, outputs: Dict[str, np.ndarray]):
+        """Window ``widx``'s scores into the reservoir (``stage_thresholds``
+        computes its threshold)."""
+        if widx != self.next_staged:
             raise ValueError(
                 f"tracker for {self.patient!r} expected window "
-                f"{self.next_widx}, got {widx} — windows must arrive "
+                f"{self.next_staged}, got {widx} — windows must arrive "
                 f"in order exactly once")
         scores = np.asarray(outputs["scores"])
         if scores.shape[-1] != self.window_samples:
             raise ValueError(
                 f"window of {scores.shape[-1]} scores, tracker expects "
                 f"{self.window_samples}")
+        self.next_staged += 1
+        return self.fold.stage(scores)
+
+    def update(self, widx: int, outputs: Dict[str, np.ndarray],
+               fmt: str) -> TrackerUpdate:
+        """Feed window ``widx``'s pipeline outputs (needs ``scores``)."""
+        if widx == self.next_widx == self.next_staged:
+            stage_thresholds([(self, widx, outputs)], fmt)
+        if widx != self.next_widx:
+            raise ValueError(
+                f"tracker for {self.patient!r} expected window "
+                f"{self.next_widx}, got {widx} — windows must arrive "
+                f"in order exactly once")
+        scores = np.asarray(outputs["scores"])
         self.next_widx += 1
         self.windows_by_fmt[fmt] = self.windows_by_fmt.get(fmt, 0) + 1
-        new = self.fold.push(self._ar(fmt), scores)
+        new = self.fold.advance()
         self.peaks.extend(int(p) for p in new)
         return TrackerUpdate(
             self.patient, widx, fmt, new, self.fold.thr,
-            self._boundary_gap(scores), self._mid_refractory(),
-            self.fold.threshold_s)
+            self._boundary_gap(scores), self._mid_refractory())
 
     def finalize(self, fmt: str) -> np.ndarray:
         """End of stream: flush the fold's deferred lookahead margin."""
@@ -122,3 +138,19 @@ class RPeakTracker:
     def _mid_refractory(self) -> bool:
         return any(q + self.fold.refractory > self.fold.committed
                    for q in self.fold.taken)
+
+
+def stage_thresholds(
+        items: Sequence[Tuple[RPeakTracker, int, Dict[str, np.ndarray]]],
+        fmt: str,
+        on_launch: Optional[Callable[[float, float, int, int], None]] = None
+) -> None:
+    """First phase of a batch of windows in format ``fmt``: each
+    ``(tracker, widx, outputs)`` window's scores into its tracker's
+    reservoir, then every window's 2-means threshold, ``THRESHOLD_ROWS``
+    windows per launch (``apps.bayeslope.fold_thresholds``, which also
+    explains ``on_launch``).  Each tracker's windows come in ``widx``
+    order; ``RPeakTracker.update`` then takes them in that order."""
+    rows = [(tr.fold, tr.stage(widx, outputs))
+            for tr, widx, outputs in items]
+    fold_thresholds(Arith.make(fmt), rows, on_launch)

@@ -593,9 +593,10 @@ def test_fleet_64_patient_tcp_traced_bit_identical_to_untraced(pipelines):
 
 def test_traced_fleet_spans_nest_inside_each_dispatch(pipelines):
     """Each dispatch's children (stage, device, tracker, account) lie
-    inside it and cover it; every dispatched window has one threshold
-    round trip under its dispatch's tracker span; with ACKs on, every read
-    that delivered frames is followed by one ``flush_acks``."""
+    inside it and cover it; each dispatch's tracker span holds one batched
+    threshold launch, whose rows are the dispatch's windows, so the rows
+    sum to the windows dispatched; with ACKs on, every read that delivered
+    frames is followed by one ``flush_acks``."""
     tracer = Tracer()
     eng = StreamEngine({"rpeak": pipelines["rpeak"]}, max_batch=16,
                        pad_policy="max", result_capacity=None,
@@ -617,11 +618,13 @@ def test_traced_fleet_spans_nest_inside_each_dispatch(pipelines):
         assert all(par[3] <= k[3] <= k[4] <= par[4] for k in kids)
         assert self_time[par[7]] <= 0.05 * (par[4] - par[3])
     thr = [ev for ev in spans if ev[2] == "tracker.threshold"]
-    assert sorted(ev[9] for ev in thr) == sorted(
-        f"{r.patient}/{r.widx}" for r in results)
+    assert len(thr) == len(dispatches)
     for ev in thr:
         trk = by_id[ev[8]]
         assert trk[2] == "tracker" and trk[3] <= ev[3] <= ev[4] <= trk[4]
+        assert ev[6]["rows"] == by_id[trk[8]][6]["B"]
+        assert 1 <= ev[6]["depth"] <= ev[6]["rows"]
+    assert sum(ev[6]["rows"] for ev in thr) == len(results)
     reads = [ev for ev in spans if ev[1:3] == ("frame", "decode")]
     acks = [ev for ev in spans if ev[1:3] == ("frame", "flush_acks")]
     assert reads and len(acks) == len(reads)

@@ -59,7 +59,7 @@ def _stream(sig, fmt, rng, max_batch, patient="p"):
     return eng.tracker_for(patient, "rpeak"), eng.results_for(patient, "rpeak")
 
 
-@settings(max_examples=21)
+@settings(max_examples=21, deadline=None)
 @given(st.integers(0, 10**6))
 def test_streaming_peaks_equal_offline_for_any_chunking(seed):
     """≥ 20 random chunkings × {posit16, posit10, fp32}: identical peaks."""

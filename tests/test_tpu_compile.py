@@ -135,3 +135,22 @@ def test_rpeak_window_compiles(one_chip, as_on_tpu):
     text = fn.lower({"ecg": _spec(one_chip, (BATCH, 1, 500), jnp.float32)}
                     ).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("fmt", ["posit8", "posit10"])
+def test_rpeak_threshold_compiles(one_chip, as_on_tpu, fmt):
+    """The trackers' batched 2-means: one fixed-shape program per format,
+    its chain loop on the device, its posit rounds fused by XLA (no
+    kernel call inside the loop)."""
+    from repro.apps.bayeslope import (RESERVOIR_SIZE, THRESHOLD_ROWS,
+                                      _threshold_program)
+
+    R = THRESHOLD_ROWS
+    text = _threshold_program(fmt, (
+        _spec(one_chip, (R, RESERVOIR_SIZE), jnp.float32),
+        _spec(one_chip, (R,), jnp.int32),
+        _spec(one_chip, (R, 2), jnp.float32),
+        _spec(one_chip, (R,), jnp.bool_),
+        _spec(one_chip, (R,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))).as_text()
+    assert " while(" in text and "tpu_custom_call" not in text
